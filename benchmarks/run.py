@@ -22,6 +22,8 @@ def main() -> None:
                          "(per-row us_per_call) into DIR")
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (ablation_o123, common, density_analysis,
                             end_to_end, format_crossover, fused,
                             granularity_baselines, memory_overhead,

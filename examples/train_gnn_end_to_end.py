@@ -11,11 +11,13 @@ two-subgraph decomposition).
 """
 import argparse
 
+from repro import compile_cache
 from repro.core import gnn
 from repro.graphs import graph as G
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--dataset", default="pubmed")

@@ -35,6 +35,7 @@ snapshot).  Either flag enables telemetry for the run.
 """
 import argparse
 
+from repro import compile_cache
 from repro.core import gnn
 from repro.graphs import graph as G
 from repro.obs import enable_verbose
@@ -46,6 +47,7 @@ log = enable_verbose("repro.train")
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--dataset", default="pubmed")

@@ -222,7 +222,9 @@ def coo_to_blockdiag(coo: COO, block_size: int) -> BlockDiag:
     blocks = np.zeros((nb, B, B), np.float32)
     b = rows // B
     assert np.all(b == cols // B), "coo_to_blockdiag: edge off the block diagonal"
-    blocks[b, rows % B, cols % B] = vals
+    # add, not assign: a repeated edge (a graph's own self-loop plus the
+    # one GCN adds) counts as often as it occurs, as in every other format
+    np.add.at(blocks, (b, rows % B, cols % B), vals)
     return BlockDiag(n_pad, B, blocks)
 
 
@@ -256,7 +258,7 @@ def coo_to_bell(coo: COO, block_size: int, n_cols_pad: int | None = None,
         n_valid[i] = len(p)
     for r in range(len(rows)):
         i, j = int(brow[r]), int(bcol[r])
-        blocks[i, blk_of[(i, j)], rows[r] % B, cols[r] % B] = vals[r]
+        blocks[i, blk_of[(i, j)], rows[r] % B, cols[r] % B] += vals[r]
     return BlockELL(n_rpad, n_cpad, B, K, f_tile_cap,
                     blocks=blocks, col_idx=col_idx, n_valid=n_valid)
 
@@ -337,8 +339,9 @@ def coo_to_bell_capped(coo: COO, block_size: int, k_max: int,
             sb = np.flatnonzero(slot < K)
             col_idx[ubrow[sb], slot[sb]] = ubcol[sb]
             n_valid[:] = np.minimum(np.bincount(ubrow, minlength=nbr), K)
-            blocks[brow[stored_m], edge_slot[stored_m],
-                   rows[stored_m] % B, cols[stored_m] % B] = vals[stored_m]
+            np.add.at(blocks, (brow[stored_m], edge_slot[stored_m],
+                               rows[stored_m] % B, cols[stored_m] % B),
+                      vals[stored_m])
     else:
         stored_m = np.zeros(0, bool)
 

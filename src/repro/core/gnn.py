@@ -311,11 +311,15 @@ def _loss(params, cfg, dec, x, labels, node_mask, plan, inv_deg):
     return nll.sum() / jnp.maximum(node_mask.sum(), 1)
 
 
-def make_train_step(cfg: GNNConfig, dec, kernels, inv_deg):
-    """SGD-with-Adam step over the full graph; jitted once per KernelPlan."""
-    plan = _as_plan(dec, kernels, cfg.n_layers)
+def make_train_step(cfg: GNNConfig, plan: KernelPlan):
+    """jit step(params, opt, dec, x, labels, node_mask, inv_deg) ->
+    (params, opt, loss): SGD-with-Adam over the full graph, one per
+    KernelPlan.
 
-    def step(params, opt, x, labels, node_mask):
+    The graph is an argument, not a constant: a closed-over ``dec`` would
+    be baked into the executable (gigabytes of HLO constants at Table-1
+    sizes) and into its compile-cache key."""
+    def step(params, opt, dec, x, labels, node_mask, inv_deg):
         loss, grads = jax.value_and_grad(_loss)(
             params, cfg, dec, x, labels, node_mask, plan, inv_deg)
         new_params, new_opt = _adam_update(params, grads, opt, cfg.lr)
@@ -351,6 +355,11 @@ class TrainResult:
     step_seconds: float
     preprocess_seconds: float
     plan: Any = None       # the full KernelPlan
+    select_seconds: float = 0.0    # kernel selection (feedback probes)
+    compile_seconds: float = 0.0   # AOT compile of the train step
+    step: Any = None       # the compiled train step (jax.stages.Compiled)
+    params: Any = None     # trained model params
+    dec: Any = None        # the decomposition the step ran over
 
 
 def select_plan(dec: dec_mod.Decomposed, cfg: GNNConfig,
@@ -446,19 +455,24 @@ def train(graph: graph_mod.Graph, cfg: GNNConfig, steps: int = 50,
     # --- kernel selection (per layer: aggregation width differs by layer;
     # transform-first layers carry their input width so fused candidates
     # compete — GCN natively, GIN/SAGE through the epilogue rewrite)
+    t0 = time.perf_counter()
     pairs, eps = layer_plan_inputs(cfg, x.shape[-1], graph.n_classes,
                                    dec=dec, dtype=x.dtype)
     plan, probe_times = select_plan(dec, cfg, pairs, dtype=x.dtype,
                                     epilogues=eps)
+    t_sel = time.perf_counter() - t0
 
-    step_fn = make_train_step(cfg, dec, plan, inv_deg)
+    args = (dec, x, labels_r, node_mask, inv_deg)
+    t0 = time.perf_counter()
+    step_fn = make_train_step(cfg, plan).lower(params, opt, *args).compile()
+    t_compile = time.perf_counter() - t0
 
     losses = []
     t_step0 = None
     for i in range(steps):
         if i == 1:
             t_step0 = time.perf_counter()
-        params, opt, loss = step_fn(params, opt, x, labels_r, node_mask)
+        params, opt, loss = step_fn(params, opt, *args)
         losses.append(float(loss))
         if verbose and i % 10 == 0:
             print(f"step {i:4d} loss {float(loss):.4f} plan={plan.layers}")
@@ -472,4 +486,6 @@ def train(graph: graph_mod.Graph, cfg: GNNConfig, steps: int = 50,
     return TrainResult(losses=losses, accuracy=acc,
                        kernels=[tuple(k) for k in plan.layers],
                        probe_times=probe_times, step_seconds=step_s,
-                       preprocess_seconds=t_pre, plan=plan)
+                       preprocess_seconds=t_pre, plan=plan,
+                       select_seconds=t_sel, compile_seconds=t_compile,
+                       step=step_fn, params=params, dec=dec)

@@ -58,9 +58,21 @@ class HwModel:
 CPU_HW = HwModel(name="cpu_interpret", peak_flops=5e10, hbm_bw=2e10,
                  launch_overhead_s=5e-5)
 
+# Cost-model constants keyed by ``jax.Device.device_kind``; a TPU v5e
+# reports "TPU v5 lite".
+HW_BY_KIND = {"cpu": CPU_HW, "TPU v5 lite": HwModel()}
 
-def default_hw() -> HwModel:
-    return CPU_HW if jax.default_backend() == "cpu" else HwModel()
+
+def default_hw(device_kind: str | None = None) -> HwModel:
+    """Constants for ``device_kind`` (default: the first JAX device's).
+    A kind missing from :data:`HW_BY_KIND` raises: peak rates assumed for
+    a device nobody described would price every kernel wrongly."""
+    kind = jax.devices()[0].device_kind if device_kind is None else device_kind
+    try:
+        return HW_BY_KIND[kind]
+    except KeyError:
+        raise ValueError(f"no cost-model constants for device kind {kind!r}; "
+                         f"known: {sorted(HW_BY_KIND)}") from None
 
 
 def dense_transform_cost(n: int, fin: int, fout: int, dtype=np.float32,
@@ -194,23 +206,28 @@ def plan_modeled_costs(dec: Decomposed, layers, pairs, dtype=np.float32,
     return out
 
 
+def _probe_fn(sub: Subgraph, spec):
+    """``(jitted kernel, device payload)`` for timing one candidate.  The
+    payload is an argument, not a closed-over constant, so the probe
+    compiles the same program the train step runs, without baking the
+    subgraph into the executable; it is placed on device once, so no
+    timed call re-uploads host payloads."""
+    payload = jax.device_put(sub.formats[spec.payload_key])
+    return jax.jit(spec.fused_matvec if spec.fused else spec.matvec), payload
+
+
 def _time_candidate(sub: Subgraph, spec, fin: int | None, fout: int,
                     dtype, iters: int) -> float:
     """Median wall seconds for one candidate on synthetic full-width
     operands (compile excluded) — the measurement unit probe_topk and the
-    PlanCache's Nth-miss probe share with the full-batch feedback path."""
-    from repro.core import adaptgear  # local import to avoid cycle
+    PlanCache's Nth-miss probe share with the full-batch feedback path.
+    The payload is a jit argument (see :func:`_probe_fn`)."""
+    fn, payload = _probe_fn(sub, spec)
     if spec.fused:
-        x_in = jnp.ones((sub.n_rows, fin), dtype)
-        w = jnp.ones((fin, fout), dtype)
-        fn = jax.jit(lambda xi, wi, s=sub, k=spec.name:
-                     adaptgear.aggregate_sub_fused(s, xi, wi, k))
-        args = (x_in, w)
+        args = (payload, jnp.ones((sub.n_rows, fin), dtype),
+                jnp.ones((fin, fout), dtype))
     else:
-        x = jnp.ones((sub.n_rows, fout), dtype)
-        fn = jax.jit(lambda xx, s=sub, k=spec.name:
-                     adaptgear.aggregate_sub(s, xx, k))
-        args = (x,)
+        args = (payload, jnp.ones((sub.n_rows, fout), dtype))
     fn(*args).block_until_ready()          # compile outside the timing
     ts = []
     for _ in range(iters):
@@ -427,7 +444,6 @@ class AdaptiveSelector:
         argmin an honest whole-layer comparison.  ``free_transform`` (GIN's
         MLP epilogue: the self term computes H regardless) keeps the fused
         probes but zeroes that surcharge."""
-        from repro.core import adaptgear  # local import to avoid cycle
         share = 0.0
         if transform is not None:
             x_in, w_mat = transform
@@ -446,17 +462,14 @@ class AdaptiveSelector:
         wk = self._wkey(width)
         for sub in self.dec.subgraphs:
             for spec in self._cands(sub):
+                if spec.fused and transform is None:
+                    continue
+                fn, payload = _probe_fn(sub, spec)
                 if spec.fused:
-                    if transform is None:
-                        continue
-                    fn = jax.jit(lambda xi, wi, s=sub, k=spec.name:
-                                 adaptgear.aggregate_sub_fused(s, xi, wi, k))
-                    args = (x_in, w_mat)
+                    args = (payload, x_in, w_mat)
                     extra = 0.0
                 else:
-                    fn = jax.jit(lambda xx, s=sub, k=spec.name:
-                                 adaptgear.aggregate_sub(s, xx, k))
-                    args = (x,)
+                    args = (payload, x)
                     extra = share
                 fn(*args).block_until_ready()  # compile outside the timing
                 for _ in range(iters):

@@ -68,7 +68,7 @@ def _kernel_acc(idx_ref, a_ref, x_ref, y_ref, o_ref, acc_ref):
 @functools.partial(jax.jit, static_argnames=("f_tile", "interpret"))
 def bell_spmm(blocks: jax.Array, col_idx: jax.Array, x: jax.Array,
               y_in: jax.Array | None = None, *,
-              f_tile: int = 512, interpret: bool = True) -> jax.Array:
+              f_tile: int = 512, interpret: bool) -> jax.Array:
     """Y = A_bell @ x (+ y_in).
 
     blocks: (nbr, K, B, B); col_idx: (nbr, K) int32; x: (nbc*B, F);
@@ -103,8 +103,7 @@ def bell_spmm(blocks: jax.Array, col_idx: jax.Array, x: jax.Array,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((nbr, B, F), x.dtype),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*operands)
     return out.reshape(nbr * B, F)

@@ -70,7 +70,7 @@ def _kernel_acc(idx_ref, a_ref, x_ref, w_ref, y_ref, o_ref, acc_ref):
 @functools.partial(jax.jit, static_argnames=("f_tile", "interpret"))
 def bell_spmm_fused(blocks: jax.Array, col_idx: jax.Array, x: jax.Array,
                     w: jax.Array, y_in: jax.Array | None = None, *,
-                    f_tile: int = 512, interpret: bool = True) -> jax.Array:
+                    f_tile: int = 512, interpret: bool) -> jax.Array:
     """Y = A_bell @ (x @ w) (+ y_in).
 
     blocks: (nbr, K, B, B); col_idx: (nbr, K) int32; x: (nbc*B, Fi);
@@ -109,9 +109,8 @@ def bell_spmm_fused(blocks: jax.Array, col_idx: jax.Array, x: jax.Array,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((nbr, B, Fo), x.dtype),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*operands)
     return out.reshape(nbr * B, Fo)
 
@@ -144,7 +143,7 @@ def _dw_kernel(idx_ref, a_ref, x_ref, g_ref, o_ref, acc_ref):
 @functools.partial(jax.jit, static_argnames=("fi_tile", "fo_tile", "interpret"))
 def bell_spmm_dw(blocks_t: jax.Array, col_idx_t: jax.Array, x: jax.Array,
                  g: jax.Array, *, fi_tile: int = 512, fo_tile: int = 512,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """dW = X^T @ (A^T @ G), A^T given in blocked-ELL (transpose payload).
 
     blocks_t: (nbr, K, B, B); col_idx_t: (nbr, K) int32; x: (nbr*B, Fi);
@@ -179,8 +178,7 @@ def bell_spmm_dw(blocks_t: jax.Array, col_idx_t: jax.Array, x: jax.Array,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((Fi, Fo), jnp.float32),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel",
+                                 "arbitrary", "arbitrary")),
     )(col_idx_t, blocks_t, xb, gb)

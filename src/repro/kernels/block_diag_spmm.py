@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(a_ref, x_ref, o_ref):
@@ -34,7 +35,7 @@ def _kernel_acc(a_ref, x_ref, y_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("f_tile", "interpret"))
 def block_diag_spmm(blocks: jax.Array, x: jax.Array,
                     y_in: jax.Array | None = None, *,
-                    f_tile: int = 512, interpret: bool = True) -> jax.Array:
+                    f_tile: int = 512, interpret: bool) -> jax.Array:
     """Y = blockdiag(blocks) @ x (+ y_in).
 
     blocks: (nb, B, B); x: (nb*B, F) with F % f_tile == 0 (ops.py pads);
@@ -65,8 +66,7 @@ def block_diag_spmm(blocks: jax.Array, x: jax.Array,
         out_specs=pl.BlockSpec((None, B, f_tile), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((nb, B, F), x.dtype),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
     )(*operands)
     return out.reshape(n, F)

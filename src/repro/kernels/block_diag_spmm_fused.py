@@ -35,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(a_ref, x_ref, w_ref, o_ref):
@@ -53,7 +54,7 @@ def _kernel_acc(a_ref, x_ref, w_ref, y_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("f_tile", "interpret"))
 def block_diag_spmm_fused(blocks: jax.Array, x: jax.Array, w: jax.Array,
                           y_in: jax.Array | None = None, *,
-                          f_tile: int = 512, interpret: bool = True
+                          f_tile: int = 512, interpret: bool
                           ) -> jax.Array:
     """Y = blockdiag(blocks) @ (x @ w) (+ y_in).
 
@@ -87,9 +88,8 @@ def block_diag_spmm_fused(blocks: jax.Array, x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((None, B, f_tile), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((nb, B, Fo), x.dtype),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
     )(*operands)
     return out.reshape(n, Fo)
 
@@ -117,7 +117,7 @@ def _kernel_dual_acc(a_ref, x_ref, w_ref, ws_ref, y_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("f_tile", "interpret"))
 def block_diag_spmm_dual(blocks: jax.Array, x: jax.Array, w: jax.Array,
                          w_self: jax.Array, y_in: jax.Array | None = None, *,
-                         f_tile: int = 512, interpret: bool = True
+                         f_tile: int = 512, interpret: bool
                          ) -> jax.Array:
     """Y = blockdiag(blocks) @ (x @ w) + x @ w_self (+ y_in).
 
@@ -157,8 +157,7 @@ def block_diag_spmm_dual(blocks: jax.Array, x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((None, B, f_tile), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((nb, B, Fo), x.dtype),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
     )(*operands)
     return out.reshape(n, Fo)

@@ -72,7 +72,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "blk_q", "blk_k",
                                              "interpret", "scale"))
 def flash_attention(q, k, v, *, causal: bool = True, blk_q: int = 128,
-                    blk_k: int = 128, interpret: bool = True,
+                    blk_k: int = 128, interpret: bool,
                     scale: float | None = None):
     """q: (B, Hq, Sq, d); k: (B, Hkv, Skv, d); v: (B, Hkv, Skv, dv)
     -> (B, Hq, Sq, dv).  Sq % blk_q == 0 and Skv % blk_k == 0 (pad
@@ -112,10 +112,8 @@ def flash_attention(q, k, v, *, causal: bool = True, blk_q: int = 128,
                         pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, dv), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qf, kf, vf)
     return out.reshape(B, Hq, Sq, dv)
 

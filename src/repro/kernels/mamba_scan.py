@@ -57,7 +57,7 @@ def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_ref):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "d_tile", "interpret"))
 def mamba_scan(x, dt, Bc, Cc, A, D, *, chunk: int = 128, d_tile: int = 512,
-               interpret: bool = True):
+               interpret: bool):
     """x, dt: (B, T, d_inner); Bc, Cc: (B, T, d_state);
     A: (d_inner, d_state); D: (d_inner,) -> y (B, T, d_inner).
     dt is post-softplus.  T % chunk == 0; d_inner % d_tile == 0."""
@@ -82,10 +82,8 @@ def mamba_scan(x, dt, Bc, Cc, A, D, *, chunk: int = 128, d_tile: int = 512,
         out_shape=jax.ShapeDtypeStruct((B, T, di), x.dtype),
         scratch_shapes=[pltpu.VMEM((d_tile, ds), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, dt, Bc, Cc, A, D.reshape(1, di))
     return out
 

@@ -125,7 +125,7 @@ def _pallas_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_chunked_pallas(r, k, v, w, u, *, chunk: int = 32,
-                         interpret: bool = True):
+                         interpret: bool):
     """Forward-only Pallas evaluation. Shapes as rwkv6_chunked; state starts
     at zero (serving prefill).  Grid: (B*H parallel, T/C sequential)."""
     B, H, T, dh = r.shape
@@ -154,9 +154,8 @@ def rwkv6_chunked_pallas(r, k, v, w, u, *, chunk: int = 32,
         out_shape=jax.ShapeDtypeStruct((B * H, T, dh), r.dtype),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(rf, kf, vf, lwf, uf)
     return out.reshape(B, H, T, dh)
 

@@ -125,7 +125,7 @@ def coo_to_tcgnn(coo: formats.COO, block_size: int,
     gather_idx = np.zeros((nbr, C), np.int32)
     if len(rows):
         gather_idx[ubrow, slot] = ucol
-        tiles[brow, rows % B, edge_slot] = vals
+        np.add.at(tiles, (brow, rows % B, edge_slot), vals)
     return TcgnnTile(n_rpad, coo.n_cols, B, C, f_tile_cap,
                      tiles=jnp.asarray(tiles),
                      gather_idx=jnp.asarray(gather_idx))
@@ -178,8 +178,8 @@ def coo_to_tcgnn_capped(coo: formats.COO, block_size: int, c_max: int,
         if build_tiles:
             sb = np.flatnonzero(slot < C)
             gather_idx[ubrow[sb], slot[sb]] = ucol[sb]
-            tiles[brow[stored_m], rows[stored_m] % B,
-                  edge_slot[stored_m]] = vals[stored_m]
+            np.add.at(tiles, (brow[stored_m], rows[stored_m] % B,
+                              edge_slot[stored_m]), vals[stored_m])
     else:
         stored_m = np.zeros(0, bool)
     tc = (TcgnnTile(n_rpad, coo.n_cols, B, C, f_tile_cap, budgeted=True,
@@ -294,7 +294,7 @@ def _mv_kernel_acc(a_ref, xg_ref, y_ref, o_ref, acc_ref):
                    static_argnames=("f_tile", "c_tile", "interpret"))
 def tcgnn_spmm(tiles: jax.Array, xg: jax.Array,
                y_in: jax.Array | None = None, *, f_tile: int = 512,
-               c_tile: int = C_TILE_CAP, interpret: bool = True
+               c_tile: int = C_TILE_CAP, interpret: bool
                ) -> jax.Array:
     """Y = condensed contraction tiles @ xg (+ y_in).
 
@@ -326,10 +326,8 @@ def tcgnn_spmm(tiles: jax.Array, xg: jax.Array,
         out_shape=jax.ShapeDtypeStruct((nbr, B, F), xg.dtype),
         scratch_shapes=[pltpu.VMEM((B, f_tile), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*operands)
     return out.reshape(nbr * B, F)
 
@@ -370,7 +368,7 @@ def _fmv_kernel_acc(a_ref, xg_ref, w_ref, y_ref, o_ref, acc_ref):
                    static_argnames=("f_tile", "c_tile", "interpret"))
 def tcgnn_spmm_fused(tiles: jax.Array, xg: jax.Array, w: jax.Array,
                      y_in: jax.Array | None = None, *, f_tile: int = 512,
-                     c_tile: int = C_TILE_CAP, interpret: bool = True
+                     c_tile: int = C_TILE_CAP, interpret: bool
                      ) -> jax.Array:
     """Y = tiles @ (xg @ w) (+ y_in): the gathered (c_tile, Fi) feature
     stripe is transformed in VMEM and immediately contracted — H never
@@ -408,10 +406,8 @@ def tcgnn_spmm_fused(tiles: jax.Array, xg: jax.Array, w: jax.Array,
         out_shape=jax.ShapeDtypeStruct((nbr, B, Fo), xg.dtype),
         scratch_shapes=[pltpu.VMEM((B, f_tile), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*operands)
     return out.reshape(nbr * B, Fo)
 
@@ -441,7 +437,7 @@ def _dw_kernel(a_ref, g_ref, x_ref, o_ref, acc_ref):
                                              "c_tile", "interpret"))
 def tcgnn_spmm_dw(tiles_t: jax.Array, gg: jax.Array, x: jax.Array, *,
                   fi_tile: int = 512, fo_tile: int = 512,
-                  c_tile: int = C_TILE_CAP, interpret: bool = True
+                  c_tile: int = C_TILE_CAP, interpret: bool
                   ) -> jax.Array:
     """dW = X^T @ (A^T @ G), A^T given as the condensed transpose payload,
     as a single blocked reduction sum_{i,k} x_i^T (tiles_t[i,k] @ gg[i,k])
@@ -475,10 +471,9 @@ def tcgnn_spmm_dw(tiles_t: jax.Array, gg: jax.Array, x: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((Fi, Fo), jnp.float32),
         scratch_shapes=[pltpu.VMEM((fi_tile, fo_tile), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel",
+                                 "arbitrary", "arbitrary")),
     )(tiles_t, gg, xb)
 
 

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import gnn
 from repro.graphs import graph as graph_mod
 from repro.obs import Telemetry
@@ -29,7 +30,8 @@ from repro.train.gnn_steps import train_minibatch
 def build_server(dataset: str = "cora", scale: float = 0.2,
                  train_steps: int = 20, seed: int = 0,
                  batch_nodes: int = 32, fanouts: tuple = (4, 2),
-                 model: str = "gcn", serve_cfg: ServeConfig | None = None,
+                 model: str = "gcn", hidden: int = 16,
+                 serve_cfg: ServeConfig | None = None,
                  telemetry: Telemetry | None = None,
                  verbose: bool = False) -> InferenceServer:
     """Train a small model and stand up a server over it, sharing the
@@ -37,7 +39,7 @@ def build_server(dataset: str = "cora", scale: float = 0.2,
     g = graph_mod.synth_dataset(dataset, scale=scale, seed=seed)
     cfg = gnn.GNNConfig(model=model, sampler="neighbor",
                         batch_nodes=batch_nodes, fanouts=tuple(fanouts),
-                        hidden=16, seed=seed)
+                        hidden=hidden, seed=seed)
     res = train_minibatch(g, cfg, steps=train_steps, verbose=verbose,
                           eval_batches=1)
     return InferenceServer(g, cfg, res.params, serve_cfg=serve_cfg,
@@ -65,6 +67,7 @@ def open_loop_burst(server: InferenceServer, qps: float, seconds: float,
 
 
 def main(argv=None) -> dict:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--dataset", default="cora")
     ap.add_argument("--scale", type=float, default=0.2)

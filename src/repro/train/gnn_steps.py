@@ -156,9 +156,9 @@ def make_sampled_step(cfg: gnn.GNNConfig, plan, counters: dict):
     """jit step(params, opt, dec, x, labels, target_mask, inv_deg)
     -> (params, opt, loss, finite).
 
-    ``dec`` is a *traced argument* (unlike the full-batch step, which
-    closes over its static decomposition): its payload arrays change every
-    batch while its structure — after :func:`fix_shapes` — does not.
+    ``dec`` is a *traced argument*, as in the full-batch step: its payload
+    arrays change every batch while its structure — after
+    :func:`fix_shapes` — does not.
     ``counters['traces']`` increments once per retrace, making the
     no-retrace contract observable by tests and benchmarks.
 

@@ -104,3 +104,26 @@ def test_property_all_formats_agree(n, nnz, f, seed):
     y_bell = np.asarray(ref.bell_spmm(bell.blocks, bell.col_idx,
                                       jnp.asarray(xp)))[:n]
     np.testing.assert_allclose(y_bell, y_ref, atol=1e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("budget", [None, 512])
+@pytest.mark.parametrize("kind", ["diag", "offdiag"])
+def test_repeated_edges_count_in_every_format(rng, kind, budget):
+    """An edge listed twice (a graph's own self-loop plus the one GCN adds)
+    counts twice in every kernel's payload, as in the COO sum."""
+    from repro.core import adaptgear, decompose
+    from repro.kernels.registry import REGISTRY
+    n, B = 64, 16
+    coo = random_coo(rng, n, 300, block_diag_only=(kind == "diag"), B=B)
+    r, c, v = (np.asarray(a) for a in (coo.rows, coo.cols, coo.vals))
+    keep = ((r // B) == (c // B)) == (kind == "diag")
+    r, c, v = (np.concatenate([a[keep], a[keep][:20]]) for a in (r, c, v))
+    order = np.argsort(r, kind="stable")
+    sub = decompose.build_subgraph("t", kind, n, B, r[order], c[order],
+                                   v[order], edge_budget=budget)
+    x = rng.standard_normal((n, 8)).astype(np.float32)
+    want = np.zeros((n, 8), np.float32)
+    np.add.at(want, r, v[:, None] * x[c])
+    for spec in REGISTRY.candidates_for(sub):
+        got = adaptgear.aggregate_sub(sub, jnp.asarray(x), spec.name)
+        np.testing.assert_allclose(got, want, atol=1e-5, err_msg=spec.name)

@@ -116,3 +116,11 @@ def test_calibration_scales_model(rng):
     t_est = selector.candidate_cost(dec.inters[0], "coo", 16, hw=hw)
     t_obs = np.median(sel._times[("inter", "coo", (0, 16))])
     assert t_est > 0 and 1e-3 < t_obs / t_est < 1e3
+
+
+def test_default_hw_keyed_by_device_kind():
+    assert selector.default_hw("cpu") is selector.CPU_HW
+    assert selector.default_hw("TPU v5 lite").peak_flops == 197e12
+    assert selector.default_hw() is selector.CPU_HW   # tests run on CPU
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        selector.default_hw("TPU v9 imaginary")

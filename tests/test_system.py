@@ -1,4 +1,6 @@
 """End-to-end behaviour tests for the paper's system (AdaptGear)."""
+from pathlib import Path
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -93,3 +95,37 @@ def test_lm_moe_adaptgear_hook():
     assert B.choose_moe_path(moe16, n_tokens=1 << 20) == "sparse"
     v3 = configs.get_config("deepseek_v3_671b").moe_cfg()
     assert B.choose_moe_path(v3, n_tokens=1 << 20) == "sparse"
+
+
+def test_train_step_takes_graph_as_argument(citeseer):
+    """The full-batch step lowers with the decomposition as arguments, not
+    as constants: no array constant beyond a few scalars' worth, so the
+    graph stays out of the executable and its compile-cache key."""
+    import re
+    import jax
+    cfg = gnn.GNNConfig(model="gcn", selector="fixed", hidden=16)
+    dec = gnn.prepare(citeseer, cfg)
+    plan = gnn.KernelPlan.make(dec, ("block_diag", "bell"), n_layers=2)
+    params = gnn.init_model(jax.random.PRNGKey(0), cfg,
+                            citeseer.features.shape[1], citeseer.n_classes)
+    n = dec.n_pad
+    lowered = gnn.make_train_step(cfg, plan).lower(
+        params, gnn._adam_init(params), dec,
+        jnp.zeros((n, citeseer.features.shape[1])),
+        jnp.zeros((n,), jnp.int32), jnp.ones((n,), bool), jnp.ones((n,)))
+    consts = re.findall(r"stablehlo\.constant dense<.*?>\s*:\s*tensor<([^>]*)>",
+                        lowered.as_text())
+    sizes = [int(np.prod([int(d) for d in c.split("x")[:-1]] or [1]))
+             for c in consts]
+    assert max(sizes, default=0) <= 128, sorted(sizes)[-5:]
+    # every payload leaf crosses the jit boundary
+    n_args = len(jax.tree.leaves(lowered.args_info))
+    assert n_args >= len(jax.tree.leaves(dec)) + len(jax.tree.leaves(params))
+
+
+def test_compile_cache_dir_placed_from_outside(tmp_path):
+    from repro import compile_cache
+    env = {compile_cache.ENV: str(tmp_path)}
+    assert compile_cache.cache_dir(env) == str(tmp_path)
+    repo = Path(__file__).resolve().parents[1]
+    assert compile_cache.cache_dir({}) == str(repo / ".jax_cache")
