@@ -167,10 +167,12 @@ Observability (repro.obs, wired through the whole column above) is one
 Telemetry facade with three instruments and a hard contract:
 
   * span tracer -- every pipeline stage (draw -> build -> resolve ->
-    finish -> device step), checkpoint write, probe, and retry backoff
-    opens a thread-attributed span; export is Chrome trace-event JSON
-    (cfg.trace_out), one swim lane per worker thread, so the overlap the
-    pipeline claims is inspectable per run.
+    finish -> device step, with the consumer's pipeline.get,
+    step.dispatch and step.sync), checkpoint write, probe, and retry
+    backoff opens a thread-attributed span; export is Chrome trace-event
+    JSON (cfg.trace_out), one swim lane per worker thread, so the overlap
+    the pipeline claims is inspectable per run.  Each span is also a
+    jax.profiler annotation (repro.<name>), on the device's timeline.
   * metrics registry -- thread-safe counters/gauges/bounded histograms
     (p50/p99) that PlanCache, BatchPipeline, CheckpointManager, and the
     fault-tolerance loop publish into; the legacy dict views

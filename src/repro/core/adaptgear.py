@@ -63,6 +63,17 @@ def aggregate_sub_fused(sub: Subgraph, x: jax.Array, w: jax.Array,
     return spec.fused_matvec(sub.formats[spec.payload_key], x, w)
 
 
+def tier_scope(sub: Subgraph, kernel: str):
+    """``agg/<tier>/<kernel>`` on the name stack of one subgraph's dispatch:
+    compile-time metadata only (each op's ``op_name``), read back from a
+    compiled step by :func:`repro.obs.op_scopes`.  It changes no op,
+    fusion or XLA instruction name; a Pallas call's instruction name,
+    which XLA takes from the innermost name-stack component, loses its
+    transform prefix under it (``jvp_jit_bell_spmm__.2`` becomes
+    ``bell_spmm.2``)."""
+    return jax.named_scope(f"agg/{sub.name}/{kernel}")
+
+
 def aggregate(dec: Decomposed, x: jax.Array,
               kernels: Sequence[str] = DEFAULT_KERNELS, *,
               acc: bool | None = None) -> jax.Array:
@@ -84,12 +95,13 @@ def aggregate(dec: Decomposed, x: jax.Array,
     for sub, k in zip(dec.subgraphs, names):
         spec = REGISTRY.get(k)
         payload = sub.formats[spec.payload_key]
-        if y is None:
-            y = spec.matvec(payload, x)
-        elif acc and spec.matvec_acc is not None:
-            y = spec.matvec_acc(payload, x, y)
-        else:
-            y = y + spec.matvec(payload, x)
+        with tier_scope(sub, k):
+            if y is None:
+                y = spec.matvec(payload, x)
+            elif acc and spec.matvec_acc is not None:
+                y = spec.matvec_acc(payload, x, y)
+            else:
+                y = y + spec.matvec(payload, x)
     return y
 
 
@@ -132,22 +144,23 @@ def aggregate_transform(dec: Decomposed, x: jax.Array, w: jax.Array,
         y = seed.astype(x.dtype)
     elif bias is not None:
         y = jnp.broadcast_to(bias.astype(x.dtype), (x.shape[0], w.shape[-1]))
-    for sub, spec in zip(dec.subgraphs, specs):
+    for sub, k, spec in zip(dec.subgraphs, names, specs):
         payload = sub.formats[spec.payload_key]
-        if spec.fused:
-            if y is None:
-                y = spec.fused_matvec(payload, x, w)
-            elif acc and spec.fused_matvec_acc is not None:
-                y = spec.fused_matvec_acc(payload, x, w, y)
+        with tier_scope(sub, k):
+            if spec.fused:
+                if y is None:
+                    y = spec.fused_matvec(payload, x, w)
+                elif acc and spec.fused_matvec_acc is not None:
+                    y = spec.fused_matvec_acc(payload, x, w, y)
+                else:
+                    y = y + spec.fused_matvec(payload, x, w)
             else:
-                y = y + spec.fused_matvec(payload, x, w)
-        else:
-            if y is None:
-                y = spec.matvec(payload, h)
-            elif acc and spec.matvec_acc is not None:
-                y = spec.matvec_acc(payload, h, y)
-            else:
-                y = y + spec.matvec(payload, h)
+                if y is None:
+                    y = spec.matvec(payload, h)
+                elif acc and spec.matvec_acc is not None:
+                    y = spec.matvec_acc(payload, h, y)
+                else:
+                    y = y + spec.matvec(payload, h)
     return y
 
 
@@ -180,14 +193,16 @@ def aggregate_transform_dual(dec: Decomposed, x: jax.Array, w: jax.Array,
     first = REGISTRY.get(names[0])
     if acc and first.fused_dual_matvec is not None:
         payload = dec.subgraphs[0].formats[first.payload_key]
-        if bias is not None and acc and first.fused_dual_matvec_acc is not None:
-            y0 = jnp.broadcast_to(bias.astype(x.dtype),
-                                  (x.shape[0], w.shape[-1]))
-            seed = first.fused_dual_matvec_acc(payload, x, w, w_self, y0)
-        else:
-            seed = first.fused_dual_matvec(payload, x, w, w_self)
-            if bias is not None:
-                seed = seed + bias.astype(x.dtype)
+        with tier_scope(dec.subgraphs[0], names[0]):
+            if (bias is not None and acc
+                    and first.fused_dual_matvec_acc is not None):
+                y0 = jnp.broadcast_to(bias.astype(x.dtype),
+                                      (x.shape[0], w.shape[-1]))
+                seed = first.fused_dual_matvec_acc(payload, x, w, w_self, y0)
+            else:
+                seed = first.fused_dual_matvec(payload, x, w, w_self)
+                if bias is not None:
+                    seed = seed + bias.astype(x.dtype)
         rest = dec.subgraphs[1:]
         rest_names = names[1:]
     else:

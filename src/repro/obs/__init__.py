@@ -6,7 +6,9 @@ Three instruments, one facade:
   stages (draw -> build -> resolve -> finish -> device step, plus
   checkpoint writes and retry backoffs), exported as Chrome trace-event
   JSON (``chrome://tracing`` / Perfetto): the async overlap the pipeline
-  claims becomes visible per thread.
+  claims becomes visible per thread.  While enabled, each span is also a
+  ``repro.<name>`` annotation on ``jax.profiler``'s timeline, beside the
+  device's operations.
 * :mod:`repro.obs.metrics` — a thread-safe registry of counters, gauges,
   and bounded histograms (p50/p99).  PlanCache, BatchPipeline,
   CheckpointManager, and the fault-tolerance loop publish their counters
@@ -19,15 +21,19 @@ Three instruments, one facade:
   a cost-model calibration report (per-kernel predicted-vs-measured
   error) surfaced through ``MinibatchResult.telemetry``.
 
+:func:`op_scopes` maps a compiled step's HLO instructions to the named
+scopes they came from (``agg/<tier>/<kernel>`` in the aggregation
+dispatch), so a profile's device operations can be put down to a tier.
+
 The :class:`Telemetry` facade bundles the three.  Overhead contract:
 ``Telemetry(enabled=False)`` — the default everywhere — carries the real
 metrics registry (counters are the system of record for the stats views)
 but the null tracer and null audit, whose methods are no-ops returning
-shared singletons.  Call sites are unconditional; the disabled cost is
-measured by ``benchmarks/minibatch.py`` (``telemetry_overhead_pct``) and
-gated below 2% of the per-batch prepare cost in CI.  Telemetry never
-feeds back into decisions: tracing and auditing are append-only, so
-enabling them leaves losses, plans, hit history, and trace counts
+shared singletons.  Call sites are unconditional.  The enabled tracer's
+cost was measured on one TPU v5e at the mini-batch benchmark cell's size,
+telemetry on against off with the profiler off (PERF.md).  Telemetry
+never feeds back into decisions: tracing and auditing are append-only,
+so enabling them leaves losses, plans, hit history, and trace counts
 bit-identical (tests/test_obs.py locks this in).
 
 Logging: :func:`get_logger` / :func:`enable_verbose` give the training
@@ -43,11 +49,12 @@ import sys
 from repro.obs.audit import NULL_AUDIT, NullAudit, SelectorAudit
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry)
+from repro.obs.scopes import op_scopes  # noqa: F401
 from repro.obs.trace import NULL_TRACER, NullTracer, Tracer  # noqa: F401
 
 __all__ = ["Telemetry", "Tracer", "NullTracer", "NULL_TRACER",
            "MetricsRegistry", "Counter", "Gauge", "Histogram",
-           "SelectorAudit", "NullAudit", "NULL_AUDIT",
+           "SelectorAudit", "NullAudit", "NULL_AUDIT", "op_scopes",
            "get_logger", "enable_verbose"]
 
 

@@ -3,27 +3,44 @@
 One :class:`Tracer` instance per training run records *spans* (named,
 timed intervals) and *instants* attributed to the thread that emitted
 them.  The pipeline stages (draw -> build -> resolve -> finish -> device
-step), checkpoint writes, and retry backoffs each open a span, so the
-async overlap the pipeline claims becomes directly visible: load the
-exported file into ``chrome://tracing`` or https://ui.perfetto.dev and
-every worker thread gets its own swim lane.
+step), the consumer's wait for a batch (``pipeline.get``) and its
+dispatch and sync of the compiled step (``step.dispatch``,
+``step.sync``), checkpoint writes, and retry backoffs each open a span,
+so the async overlap the pipeline claims becomes directly visible: load
+the exported file into ``chrome://tracing`` or https://ui.perfetto.dev
+and every worker thread gets its own swim lane.
+
+While enabled, every span is also a ``jax.profiler.TraceAnnotation``
+named ``repro.<name>`` whose metadata carries the thread's tracer id
+(``thread``) and the span's ``index`` argument, so a profile taken with
+``jax.profiler`` places the program's own spans on the timeline of the
+device's operations.  Outside a profile the annotation is inert.
+
+Each span records its parent (the enclosing span on its thread) and the
+thread's CPU time (``time.thread_time``) at entry and exit beside its
+wall time, so a span's CPU seconds can be set against its wall seconds:
+a worker that waits on the GIL spends wall time without CPU time.
 
 Disabled-path contract: call sites always go through a tracer object, and
 the :data:`NULL_TRACER` singleton makes that path near-free — ``span()``
 returns one shared no-op context manager (no allocation, no clock read,
-no lock).  The hot loop's per-batch cost with tracing off is a handful of
-attribute lookups; benchmarks/minibatch.py measures it and CI gates it
-below 2% of the prepare cost (``telemetry_overhead_pct``).
+no lock, no annotation).  A mini-batch training step opens about a
+dozen spans across its threads; with tracing off each is one method call
+returning the singleton.  The enabled cost, measured on one TPU v5e at
+the mini-batch benchmark cell's size, is in PERF.md.
 
-Recording a span when *enabled* is two ``perf_counter`` reads plus one
-locked list append; events are kept as tuples and only formatted into
-Chrome trace dicts at :meth:`Tracer.export` time.  Raw OS thread ids are
-remapped to small sequential tids at export so the trace is readable,
-with ``thread_name`` metadata events carrying the Python thread names
-(``pipeline-<sampler>-<i>``, ``ckpt-writer``, ``MainThread``).
+Recording a span when *enabled* is two ``perf_counter`` and two
+``thread_time`` reads, one profiler annotation, and one locked list
+append; events are kept as tuples and only formatted into Chrome trace
+dicts at :meth:`Tracer.export` time.  Each thread gets a small sequential
+id on its first span (never the OS thread ident, which the OS reuses once
+a thread exits), with ``thread_name`` metadata events carrying the Python
+thread names (``pipeline-<sampler>-<i>``, ``ckpt-writer``,
+``MainThread``).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -35,7 +52,8 @@ __all__ = ["Tracer", "NullTracer", "NULL_TRACER"]
 class _Span:
     """Context manager for one timed interval (allocated per span only
     when tracing is enabled)."""
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_c0", "_ann",
+                 "_thread", "_id", "_parent")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -44,12 +62,26 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        tr = self._tracer
+        th = self._thread = tr._thread()
+        self._parent = th.stack[-1] if th.stack else None
+        self._id = next(tr._ids)
+        th.stack.append(self._id)
+        self._ann = tr._annotation(self.name, th.tid, self.args)
+        self._ann.__enter__()
+        self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tracer._record(self.name, self.cat, self.args,
-                             self._t0, time.perf_counter())
+        t1 = time.perf_counter()
+        c1 = time.thread_time()
+        self._ann.__exit__(*exc)
+        th = self._thread
+        th.stack.pop()
+        self._tracer._append(
+            (self.name, self.cat, th.tid, th.name, self._t0, t1, self.args,
+             self._id, self._parent, self._c0, c1))
         return False
 
 
@@ -69,16 +101,26 @@ _NULL_SPAN = _NullSpan()
 
 
 class Tracer:
-    """Collects spans/instants; exports Chrome trace-event JSON."""
+    """Collects spans/instants; exports Chrome trace-event JSON.
+
+    Each event is a tuple ``(name, cat, tid, thread_name, t0, t1, args,
+    span_id, parent_id, cpu0, cpu1)``: ``tid`` the thread's sequential
+    tracer id, ``t0``/``t1`` ``perf_counter`` seconds (``t1`` None marks
+    an instant), ``parent_id`` the ``span_id`` of the enclosing span on
+    the same thread (None at the top), ``cpu0``/``cpu1`` the thread's
+    ``thread_time`` at entry and exit (None for an instant)."""
 
     enabled = True
 
     def __init__(self) -> None:
+        from jax.profiler import TraceAnnotation
+        self._trace_annotation = TraceAnnotation
         self._epoch = time.perf_counter()
         self._lock = threading.Lock()
-        # (name, cat, tid, thread_name, t0, t1_or_None, args); t1 None
-        # marks an instant event
         self._events: list[tuple] = []
+        self._local = threading.local()
+        self._tids = itertools.count()
+        self._ids = itertools.count()
 
     # -- recording ----------------------------------------------------------
 
@@ -88,18 +130,33 @@ class Tracer:
 
     def instant(self, name: str, cat: str = "host", **args) -> None:
         """Zero-duration marker (quarantine events, slack steps, ...)."""
-        t = time.perf_counter()
-        with self._lock:
-            self._events.append(
-                (name, cat, threading.get_ident(),
-                 threading.current_thread().name, t, None, args))
+        th = self._thread()
+        with self._annotation(name, th.tid, args):
+            t = time.perf_counter()
+        self._append((name, cat, th.tid, th.name, t, None, args,
+                      next(self._ids), th.stack[-1] if th.stack else None,
+                      None, None))
 
-    def _record(self, name: str, cat: str, args: dict,
-                t0: float, t1: float) -> None:
-        tid = threading.get_ident()
-        tname = threading.current_thread().name
+    def _thread(self):
+        """The calling thread's record: its sequential ``tid``, its name,
+        and the stack of its open span ids."""
+        th = self._local
+        if not hasattr(th, "tid"):
+            th.tid = next(self._tids)
+            th.name = threading.current_thread().name
+            th.stack = []
+        return th
+
+    def _annotation(self, name: str, tid: int, args: dict):
+        index = args.get("index")
+        if index is None:
+            return self._trace_annotation("repro." + name, thread=tid)
+        return self._trace_annotation("repro." + name, thread=tid,
+                                      index=index)
+
+    def _append(self, event: tuple) -> None:
         with self._lock:
-            self._events.append((name, cat, tid, tname, t0, t1, args))
+            self._events.append(event)
 
     # -- export -------------------------------------------------------------
 
@@ -115,11 +172,9 @@ class Tracer:
         (``ph: M``) event per thread seen."""
         events = self.events()
         pid = os.getpid()
-        tid_map: dict[int, int] = {}
         tid_names: dict[int, str] = {}
         out = []
-        for name, cat, raw_tid, tname, t0, t1, args in events:
-            tid = tid_map.setdefault(raw_tid, len(tid_map))
+        for name, cat, tid, tname, t0, t1, args, *_ in events:
             tid_names[tid] = tname
             if t1 is None:
                 ev = dict(name=name, cat=cat, ph="i", s="t",

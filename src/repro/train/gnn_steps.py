@@ -336,6 +336,7 @@ class _InFlight:
     slack: float | None          # bell slack the skeleton was built with
     sample_s: float
     prepare_s: float
+    index: int | None = None     # the sampler's batch index (None: eval)
     dec: dec_mod.Decomposed | None = None
     plan: KernelPlan | None = None
     sig: tuple | None = None
@@ -549,18 +550,19 @@ def train_minibatch(graph: graph_mod.Graph, cfg: gnn.GNNConfig,
             skel_cache.put(skey, (skel, inv_deg))
         return skel, inv_deg
 
-    def build_batch(batch, sample_s) -> _InFlight:
+    def build_batch(batch, sample_s, index=None) -> _InFlight:
         """Racing stage: the partition pass into a skeleton — reading a
         *speculative* bell slack when the budget-K autotuner is live (the
         ordered resolve stage rebuilds on the rare mid-flight ladder
         step) — plus the fixed selector's payloads, which involve no
         shared-state decision."""
         t0 = time.perf_counter()
-        with tracer.span("build", cat="host"):
+        with tracer.span("build", cat="host", index=index):
             slack = cache.bell_slack if cfg.adapt_budget_k else None
             skel, inv_deg = skeleton_for(batch, slack)
             c = _InFlight(batch=batch, skel=skel, inv_deg=inv_deg,
-                          slack=slack, sample_s=sample_s, prepare_s=0.0)
+                          slack=slack, sample_s=sample_s, prepare_s=0.0,
+                          index=index)
             if fixed_names is not None and not cfg.adapt_budget_k:
                 c.dec = skel.materialize(fixed_names)
                 c.plan = KernelPlan.make(c.dec, fixed_names,
@@ -581,7 +583,7 @@ def train_minibatch(graph: graph_mod.Graph, cfg: gnn.GNNConfig,
         runs here too — the sync loop pays it at the same point, and
         steady-state misses are rare."""
         t0 = time.perf_counter()
-        with tracer.span("resolve", cat="host"):
+        with tracer.span("resolve", cat="host", index=c.index):
             if cfg.adapt_budget_k:
                 slack = cache.bell_slack
                 if slack != c.slack:   # ladder stepped while c was in flight
@@ -654,7 +656,7 @@ def train_minibatch(graph: graph_mod.Graph, cfg: gnn.GNNConfig,
         and (async) stage device transfers + AOT-compile, so the
         consumer's dispatch never pays a host->device copy or a compile."""
         t0 = time.perf_counter()
-        with tracer.span("finish", cat="host"):
+        with tracer.span("finish", cat="host", index=c.index):
             if c.dec is None:
                 # tier i materializes only the payloads the plan dispatches
                 # on tier i (per-subgraph keep sets)
@@ -805,19 +807,23 @@ def train_minibatch(graph: graph_mod.Graph, cfg: gnn.GNNConfig,
                 plan, out = recover_step(item, failed_steps[plan.layers])
             else:
                 try:
-                    out = item.step(params, opt, *item.args)
-                    out[2].block_until_ready()
+                    with tracer.span("step.dispatch", cat="device",
+                                     index=gi):
+                        out = item.step(params, opt, *item.args)
+                    with tracer.span("step.sync", cat="device", index=gi):
+                        out[2].block_until_ready()
                 except Exception as exc:
                     plan, out = recover_step(item, exc)
             params, opt, loss, finite = out
-            loss.block_until_ready()
+            with tracer.span("step.sync", cat="device", index=gi):
+                loss_v, finite_v = float(loss), bool(finite)
         dt = time.perf_counter() - t0
         t_step.append(dt)
         # the measured side of the per-plan calibration report
         tele.audit.observe_step(plan.layers, dt)
-        if not bool(finite):
+        if not finite_v:
             fault["nonfinite_skips"].inc()
-        losses.append(float(loss))
+        losses.append(loss_v)
         if ckpt is not None:
             with snap_lock:
                 snap = pending_snaps.pop(gi, None)
@@ -838,7 +844,7 @@ def train_minibatch(graph: graph_mod.Graph, cfg: gnn.GNNConfig,
             bk = (f" bellK[slack={cs['bell_slack']:.2f} "
                   f"spill={cs['spill_frac']:.3f}]"
                   if "bell_slack" in cs else "")
-            _log.info(f"batch {i:4d} loss {float(loss):.4f} "
+            _log.info(f"batch {i:4d} loss {loss_v:.4f} "
                       f"cache_hit={item.hit} plan={plan.layers[0]} "
                       f"cache[h={cs['hits']} nh={cs['near_hits']} "
                       f"m={cs['misses']} ev={cs['evictions']} "
@@ -855,7 +861,7 @@ def train_minibatch(graph: graph_mod.Graph, cfg: gnn.GNNConfig,
             batch = sampler.build(ticket)
             if fault_plan is not None:
                 batch = fault_plan.on_built(ticket.index, batch)
-        return build_batch(batch, time.perf_counter() - t0)
+        return build_batch(batch, time.perf_counter() - t0, ticket.index)
 
     pipe_stats = None
     t_loop0 = time.perf_counter()

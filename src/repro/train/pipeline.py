@@ -274,7 +274,12 @@ class BatchPipeline:
     def get(self) -> Any:
         """Next item, in index order; blocks until its worker finishes.
         Re-raises the worker's exception (closing the pipeline) if that
-        item failed."""
+        item failed.  The call is the consumer's ``pipeline.get`` span."""
+        with self.tele.tracer.span("pipeline.get", cat="pipeline",
+                                   index=self._next_out):
+            return self._get()
+
+    def _get(self) -> Any:
         if self._closed:
             raise PipelineError(f"pipeline {self.name!r} is closed")
         if self._next_out >= self.n_items:
@@ -311,7 +316,7 @@ class BatchPipeline:
                 f"{mean_ready:.1f}/{self.depth} ready batches — "
                 f"{self.workers} worker(s) can't keep it half-full; raise "
                 f"pipeline_workers (or prefetch_depth) or accept "
-                f"prepare-bound steps", RuntimeWarning, stacklevel=3)
+                f"prepare-bound steps", RuntimeWarning, stacklevel=4)
 
     # -- lifecycle ----------------------------------------------------------
 

@@ -78,7 +78,161 @@ def test_tracer_attributes_spans_to_emitting_thread():
              if e["ph"] == "M"}
     assert {"obs-worker-0", "obs-worker-1", "obs-worker-2"} <= names
     tids = {e["tid"] for e in doc["traceEvents"] if e["ph"] == "X"}
-    assert len(tids) == 3 and max(tids) <= 3      # remapped, not raw idents
+    assert len(tids) == 3 and max(tids) <= 3      # tracer ids, not raw idents
+
+
+def test_tracer_ids_survive_thread_ident_reuse():
+    # one short-lived thread after another: the OS hands the next thread
+    # the ident of the one that just exited, the tracer does not
+    tr = Tracer()
+    for i in range(6):
+        t = threading.Thread(target=lambda: tr.instant("x"),
+                             name=f"short-{i}")
+        t.start()
+        t.join()
+    tids = [e[2] for e in tr.events()]
+    assert tids == list(range(6))
+    doc = tr.chrome_trace()
+    names = sorted(e["args"]["name"] for e in doc["traceEvents"]
+                   if e["ph"] == "M")
+    assert names == [f"short-{i}" for i in range(6)]
+
+
+def test_tracer_ids_exact_under_racing_threads():
+    import sys
+    tr = Tracer()
+    n_threads, n_spans = 16, 200
+
+    def worker():
+        for i in range(n_spans):
+            with tr.span("outer", index=i):
+                with tr.span("inner", index=i):
+                    pass
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=worker) for _ in range(n_threads)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ts)
+    events = tr.events()
+    assert len(events) == 2 * n_threads * n_spans
+    assert len({e[7] for e in events}) == len(events)      # span ids
+    assert sorted({e[2] for e in events}) == list(range(n_threads))
+    by_id = {e[7]: e for e in events}
+    for e in events:
+        if e[0] == "inner":       # the parent is its own thread's outer
+            parent = by_id[e[8]]
+            assert parent[0] == "outer" and parent[2] == e[2]
+            assert parent[6] == e[6]
+        else:
+            assert e[8] is None
+
+
+def test_tracer_spans_record_parent_index_and_cpu_time():
+    tr = Tracer()
+    with tr.span("outer", index=4):
+        # long enough for a thread clock that ticks every 10 ms
+        with tr.span("busy", index=4):
+            t_end = time.perf_counter() + 0.1
+            while time.perf_counter() < t_end:
+                pass
+        with tr.span("sleep"):
+            time.sleep(0.05)
+        tr.instant("mark")
+    ev = {e[0]: e for e in tr.events()}
+    # the tracer's own record keeps its leading fields' meaning
+    assert all(e[5] is None or e[5] >= e[4] for e in ev.values())
+    outer_id = ev["outer"][7]
+    assert ev["outer"][8] is None
+    assert ev["busy"][8] == ev["sleep"][8] == ev["mark"][8] == outer_id
+    assert ev["busy"][6] == dict(index=4)
+    wall = {k: e[5] - e[4] for k, e in ev.items() if e[5] is not None}
+    cpu = {k: e[10] - e[9] for k, e in ev.items() if e[5] is not None}
+    assert cpu["busy"] > 0.5 * wall["busy"]      # computing: CPU ~ wall
+    assert cpu["sleep"] < 0.5 * wall["sleep"]    # waiting: no CPU
+    assert ev["mark"][9] is None and ev["mark"][10] is None
+
+
+def _host_events(log_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
+def test_tracer_spans_reach_the_profiler(tmp_path):
+    import jax
+    tr = Tracer()
+
+    def worker():
+        with tr.span("sample", index=7):
+            with tr.span("build", index=7):
+                pass
+        tr.instant("mark")
+
+    with tr.span("setup"):             # the main thread's first span: id 0
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t = threading.Thread(target=worker, name="pipeline-test-0")
+        t.start()
+        t.join()
+        with NULL_TRACER.span("device_step", index=1):
+            pass
+        NULL_TRACER.instant("quiet")
+    finally:
+        jax.profiler.stop_trace()
+    got = sorted(_host_events(str(tmp_path)))
+    # one lane per tracer thread id (the line names say only "python3")
+    assert got == [("repro.build", dict(thread=1, index=7)),
+                   ("repro.mark", dict(thread=1)),
+                   ("repro.sample", dict(thread=1, index=7))]
+
+
+def test_minibatch_spans_share_batch_index_and_nest_on_consumer():
+    g = small_graph(n=128, e=1200)
+    cfg = gnn.GNNConfig(model="gcn", sampler="cluster", comm_size=8,
+                        clusters_per_batch=4, inter_buckets=2,
+                        reorder="bfs", prefetch_depth=2,
+                        pipeline_workers=2)
+    tele = Telemetry(enabled=True)
+    gnn_steps.train_minibatch(g, cfg, steps=5, eval_batches=0,
+                              telemetry=tele)
+    events = tele.tracer.events()
+    by_id = {e[7]: e for e in events}
+    # one batch's stages carry the batch index the sampler drew it under
+    idx = {}
+    for e in events:
+        if e[0] in ("sample", "build", "resolve", "finish", "device_step"):
+            idx.setdefault(e[0], []).append(e[6]["index"])
+    for name in ("sample", "build", "resolve", "finish", "device_step"):
+        assert sorted(idx[name]) == list(range(5)), name
+    # the consumer's step: dispatch and sync inside device_step, on the
+    # thread that waits on the pipeline
+    consumer = {e[2] for e in events if e[0] == "device_step"}
+    assert len(consumer) == 1
+    assert {e[2] for e in events if e[0] == "pipeline.get"} == consumer
+    for e in events:
+        if e[0] in ("step.dispatch", "step.sync"):
+            assert by_id[e[8]][0] == "device_step"
+            assert e[2] in consumer
+    names = [e[0] for e in events]
+    assert names.count("step.dispatch") == 5
+    assert names.count("pipeline.get") == 5
+    # the racing stages run on the workers, never on the consumer
+    assert not {e[2] for e in events if e[0] in ("build", "finish")} \
+        & consumer
 
 
 def test_tracer_export_writes_valid_json(tmp_path):
@@ -325,3 +479,35 @@ def test_telemetry_on_off_identical_through_async_pipeline():
     assert off.hit_history == on.hit_history
     assert off.n_traces == on.n_traces
     assert on.telemetry["n_span_events"] > 0
+
+
+# -- named scopes in the compiled step ---------------------------------------
+
+def test_op_scopes_name_each_tier_and_change_no_result(monkeypatch):
+    import contextlib
+    import re
+
+    from repro.core import adaptgear
+    from repro.obs import op_scopes
+    g = small_graph(n=128, e=1200)
+    cfg = gnn.GNNConfig(model="gcn", hidden=16, n_layers=2, inter_buckets=2,
+                        reorder="bfs", comm_size=8, selector="fixed",
+                        fixed_kernels=("block_diag", "csr"))
+    res = gnn.train(g, cfg, steps=3)
+    scopes = op_scopes(res.step)
+    tiers = {m.group(1) for name in scopes.values()
+             for m in re.finditer(r"agg/(\w+)/(\w+)", name)}
+    assert "intra" in tiers
+    assert any(t.startswith("inter") for t in tiers)
+    # the backward pass keeps the scope inside its transform
+    assert any(re.search(r"transpose\(jvp\(agg/intra/block_diag\)\)", n)
+               for n in scopes.values())
+    # instruction names as a profile's XLA Ops events carry them
+    assert all(re.fullmatch(r"[\w.\-]+", k) for k in scopes)
+    # scopes are metadata only: the step without them trains identically
+    monkeypatch.setattr(adaptgear, "tier_scope",
+                        lambda sub, kernel: contextlib.nullcontext())
+    bare = gnn.train(g, cfg, steps=3)
+    assert bare.losses == res.losses
+    assert not any("agg/" in n for n in op_scopes(bare.step).values())
+

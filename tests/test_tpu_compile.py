@@ -11,7 +11,9 @@ under pytest-xdist only the worker that runs this file loads it.
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -144,13 +146,12 @@ def test_kernel_compiles_for_v5e(name, f, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_gcn_train_step_compiles_for_v5e(one_chip, no_persistent_cache,
-                                         monkeypatch):
-    """The whole full-batch train step, as the chip runs it: Pallas
-    compiled (not interpreted) and the accumulating dispatch on, at the
-    published widths (500 inputs, hidden 256, 3 layers) on a small graph,
-    with a plan that puts every Pallas kernel family and its VJP in one
-    program."""
+def _gcn_step_for_v5e(one_chip, monkeypatch):
+    """The whole full-batch GCN train step compiled for v5e, as the chip
+    runs it: Pallas compiled (not interpreted) and the accumulating
+    dispatch on, at the published widths (500 inputs, hidden 256, 3
+    layers) on a small graph, with a plan that puts every Pallas kernel
+    family and its VJP in one program."""
     from repro.core import gnn
     from repro.core.plan import KernelPlan
     from repro.graphs import graph as G
@@ -174,5 +175,53 @@ def test_gcn_train_step_compiles_for_v5e(one_chip, no_persistent_cache,
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         args)
-    compiled = gnn.make_train_step(cfg, plan).lower(*shapes).compile()
+    return gnn.make_train_step(cfg, plan).lower(*shapes).compile()
+
+
+def test_gcn_train_step_compiles_for_v5e(one_chip, no_persistent_cache,
+                                         monkeypatch):
+    """The whole full-batch train step, as the chip runs it, with every
+    Pallas kernel family and its VJP in one program."""
+    compiled = _gcn_step_for_v5e(one_chip, monkeypatch)
     assert compiled.as_text().count("tpu_custom_call") >= 6
+
+
+PALLAS_CALL = re.compile(r'^\s*(?:ROOT )?%([\w.\-]+) = .*'
+                         r'custom_call_target="tpu_custom_call"', re.M)
+
+
+def test_tier_scopes_change_no_op_for_v5e(one_chip, no_persistent_cache,
+                                          monkeypatch):
+    """The ``agg/<tier>/<kernel>`` scopes are metadata: with and without
+    them the v5e step is the same program, op for op, fusion for fusion,
+    under the same instruction names, once each op's metadata is set
+    aside.  The one exception is a Pallas call's instruction name, which
+    XLA takes from the innermost name-stack component: with a scope
+    inside the transform it loses its transform prefix
+    (``jvp_jit_bell_spmm__.2`` becomes ``bell_spmm.2``)."""
+    from repro.core import adaptgear
+    from repro.obs import op_scopes
+
+    scoped = _gcn_step_for_v5e(one_chip, monkeypatch)
+    monkeypatch.setattr(adaptgear, "tier_scope",
+                        lambda sub, kernel: contextlib.nullcontext())
+    bare = _gcn_step_for_v5e(one_chip, monkeypatch)
+
+    def strip(text):
+        # the computations' instructions, without their source metadata
+        # (the module's source-location tables go too)
+        body = "\n".join(line for line in text.splitlines()
+                         if re.match(r"\s*(ROOT |ENTRY )?%", line))
+        return re.sub(r", metadata=\{[^}]*\}", "", body)
+
+    a, b = strip(scoped.as_text()), strip(bare.as_text())
+    calls_a, calls_b = PALLAS_CALL.findall(a), PALLAS_CALL.findall(b)
+    assert len(calls_a) == len(calls_b) >= 6
+    for old, new in zip(calls_b, calls_a):
+        b = re.sub(r"(?<![\w.\-])%" + re.escape(old) + r"(?![\w.\-])",
+                   "%" + new, b)
+    assert a == b
+    # each Pallas call lies under its tier's scope
+    scopes = op_scopes(scoped)
+    assert all(re.search(r"agg/(intra|inter\d)/", scopes[name])
+               for name in calls_a)
