@@ -6,11 +6,12 @@ with no edits elsewhere.
 
 Paper mapping (§2.1/§3.2): CSR is the vertex-parallel format — one worker
 per destination row walks ``indices[indptr[i]:indptr[i+1]]``.  The TPU/XLA
-analogue expands the row pointer back to per-edge row ids with a
-``searchsorted`` over the (static-shape) edge range, gathers source
-features, and reduces with a sorted segment-sum: gather-efficiency class
-(like ELL) rather than scatter class (like COO), but with zero padding —
-CSR stores exactly nnz entries where ELL pads every row to max degree.
+analogue expands the row pointer back to per-edge row ids in O(E + n)
+(one scatter of a mark at each row end, then a prefix sum over the
+static-shape edge range), gathers source features, and reduces with a
+sorted segment-sum: gather-efficiency class (like ELL) rather than
+scatter class (like COO), but with zero padding — CSR stores exactly nnz
+entries where ELL pads every row to max degree.
 """
 from __future__ import annotations
 
@@ -24,10 +25,19 @@ from repro.kernels.registry import DIAG, OFFDIAG, REGISTRY, KernelSpec
 
 def _edge_rows(csr: formats.CSR) -> jax.Array:
     """Expand the row pointer back to per-edge destination ids (sorted,
-    static shape; budget-padded entries land in the last row's segment)."""
+    static shape).  Each row end ``indptr[i+1]`` marks the edge where row
+    ``i + 1`` starts; the prefix sum of the marks counts the row ends at
+    or before each edge, which is its row.  Empty rows stack their marks
+    on one edge; ends at ``nnz`` drop.  Equals ``searchsorted(indptr,
+    arange(nnz), side="right") - 1`` for any non-decreasing ``indptr``
+    with ``indptr[0] == 0``: budget-padded entries (``indptr[-1]`` raised
+    to the budget) land in the last row's segment, and edges past
+    ``indptr[-1]`` get ``n_rows``, which the segment sums drop."""
     nnz = csr.indices.shape[0]
-    return jnp.searchsorted(csr.indptr, jnp.arange(nnz, dtype=jnp.int32),
-                            side="right").astype(jnp.int32) - 1
+    with jax.named_scope("expand"):
+        marks = jnp.zeros((nnz,), jnp.int32).at[csr.indptr[1:]].add(
+            1, mode="drop", indices_are_sorted=True)
+        return jnp.cumsum(marks, dtype=jnp.int32)
 
 
 def csr_matvec(csr: formats.CSR, x: jax.Array) -> jax.Array:

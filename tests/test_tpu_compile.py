@@ -146,6 +146,25 @@ def test_kernel_compiles_for_v5e(name, f, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_csr_expansion_has_no_loop_for_v5e(one_chip, no_persistent_cache):
+    """``csr_matvec`` at the full-batch blogcatalog cell's inter-tier shape
+    (88,784 rows, 1,556,811 edges, 256 features) compiles to straight-line
+    code: the row-pointer expansion is a scatter and a prefix sum, not a
+    binary search's ``while`` loop over every edge."""
+    from repro.core import formats
+    from repro.kernels.csr import csr_matvec
+
+    n, nnz, f = 88_784, 1_556_811, 256
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    csr = formats.CSR(n, n, spec((n + 1,), I32), spec((nnz,), I32),
+                      spec((nnz,), F32))
+    text = jax.jit(csr_matvec).lower(csr, spec((n, f), F32)).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+
+
 def _gcn_step_for_v5e(one_chip, monkeypatch):
     """The whole full-batch GCN train step compiled for v5e, as the chip
     runs it: Pallas compiled (not interpreted) and the accumulating
