@@ -285,20 +285,39 @@ def gin_conv(params: Params, dec: Decomposed, x: jax.Array,
 
         z  = (1+eps) X + A X
         y  = relu(z W1 + b1) W2 + b2
+
+    Up to the ReLU the layer computes in float32 whatever the storage dtype,
+    kernels included (``gnn.train`` prices them so): the ReLU's mask follows
+    the sign of the self term plus the tier partials, and ``S`` or a partial
+    rounded to bfloat16 flips it wherever the pre-activation sits within
+    that rounding of zero; the fused kernels' and the self term's parts of
+    dW1 nearly cancel, so they are summed in float32 too.  The self term
+    and the MLP sit under ``gin/self`` and ``gin/mlp``; the aggregation
+    keeps its own ``agg/<tier>/<kernel>`` scopes outside both.
     """
+    dtype = x.dtype
+    wide = jnp.promote_types(dtype, jnp.float32)
+    x, w1, b1 = (x.astype(wide), params["w1"].astype(wide),
+                 params["b1"].astype(wide))
+    one_eps = 1.0 + params["eps"].astype(wide)
     if structure == "aggregate_first":
         names = plan_mod.normalize_layer(dec, kernels)
         if not any(REGISTRY.get(k).fused for k in names):
-            z = (1.0 + params["eps"]) * x + aggregate(dec, x, names)
-            h1 = jax.nn.relu(z @ params["w1"] + params["b1"])
-            return h1 @ params["w2"] + params["b2"]
+            with jax.named_scope("gin/self"):
+                x_self = one_eps * x
+            agg = aggregate(dec, x, names)
+            with jax.named_scope("gin/mlp"):
+                h1 = jax.nn.relu((x_self + agg) @ w1 + b1).astype(dtype)
+                return h1 @ params["w2"] + params["b2"]
         # fused kernel names imply transform-first (A (X W1) is the only
         # pass they implement) — a pinned fused plan overrides the spec
-    s = x @ params["w1"]
-    seed = (1.0 + params["eps"]) * s + params["b1"]
-    h1 = jax.nn.relu(aggregate_transform(dec, x, params["w1"], kernels,
-                                         seed=seed, h=s))
-    return h1 @ params["w2"] + params["b2"]
+    with jax.named_scope("gin/self"):
+        s = x @ w1
+        seed = one_eps * s + b1
+    pre = aggregate_transform(dec, x, w1, kernels, seed=seed, h=s)
+    with jax.named_scope("gin/mlp"):
+        h1 = jax.nn.relu(pre).astype(dtype)
+        return h1 @ params["w2"] + params["b2"]
 
 
 def init_sage_conv(key, in_dim: int, out_dim: int) -> Params:

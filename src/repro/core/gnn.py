@@ -25,6 +25,7 @@ from repro.core import adaptgear, decompose as dec_mod, selector as sel_mod
 from repro.core import epilogue as ep_mod
 from repro.core.plan import KernelPlan
 from repro.graphs import graph as graph_mod
+from repro.obs.metrics import MetricsRegistry
 
 Params = Any
 
@@ -418,7 +419,7 @@ def select_plan(dec: dec_mod.Decomposed, cfg: GNNConfig,
 
 
 def train(graph: graph_mod.Graph, cfg: GNNConfig, steps: int = 50,
-          verbose: bool = False):
+          verbose: bool = False, metrics: MetricsRegistry | None = None):
     """Full training driver with the paper's feedback selection protocol.
 
     ``cfg.sampler != "full"`` switches to mini-batch sampled-subgraph
@@ -427,7 +428,11 @@ def train(graph: graph_mod.Graph, cfg: GNNConfig, steps: int = 50,
     MinibatchResult instead of a TrainResult.  There ``fixed`` selection
     is honored per batch, while ``feedback`` and ``cost_model`` both
     resolve to cached cost-model selection (per-batch wall-clock probing
-    cannot amortize over fresh subgraphs — see train_minibatch)."""
+    cannot amortize over fresh subgraphs — see train_minibatch).
+
+    A full-batch GIN run counts each layer's committed structure once in
+    ``metrics`` (a ``repro.obs`` registry), under
+    ``gin.structure.<structure>``."""
     if cfg.sampler != "full":
         from repro.train import gnn_steps   # lazy: avoids an import cycle
         return gnn_steps.train_minibatch(graph, cfg, steps=steps,
@@ -455,12 +460,19 @@ def train(graph: graph_mod.Graph, cfg: GNNConfig, steps: int = 50,
     # --- kernel selection (per layer: aggregation width differs by layer;
     # transform-first layers carry their input width so fused candidates
     # compete — GCN natively, GIN/SAGE through the epilogue rewrite)
+    # GIN computes up to its ReLU in float32 (adaptgear.gin_conv), so its
+    # kernels are priced and probed at float32 whatever the features' dtype
+    agg_dtype = (jnp.promote_types(x.dtype, jnp.float32)
+                 if cfg.model == "gin" else x.dtype)
     t0 = time.perf_counter()
     pairs, eps = layer_plan_inputs(cfg, x.shape[-1], graph.n_classes,
-                                   dec=dec, dtype=x.dtype)
-    plan, probe_times = select_plan(dec, cfg, pairs, dtype=x.dtype,
+                                   dec=dec, dtype=agg_dtype)
+    plan, probe_times = select_plan(dec, cfg, pairs, dtype=agg_dtype,
                                     epilogues=eps)
     t_sel = time.perf_counter() - t0
+    if metrics is not None and cfg.model == "gin":
+        for ep in plan.epilogues:
+            metrics.counter(f"gin.structure.{ep.structure}").inc()
 
     args = (dec, x, labels_r, node_mask, inv_deg)
     t0 = time.perf_counter()

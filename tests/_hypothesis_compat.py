@@ -6,7 +6,7 @@ dependency."""
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:            # clean machine: property tests skip
     HAVE_HYPOTHESIS = False
@@ -20,6 +20,11 @@ except ImportError:            # clean machine: property tests skip
     st = _StrategyStub()
 
     def settings(*args, **kwargs):
+        def deco(fn):
+            return fn
+        return deco
+
+    def example(*args, **kwargs):
         def deco(fn):
             return fn
         return deco

@@ -511,3 +511,26 @@ def test_op_scopes_name_each_tier_and_change_no_result(monkeypatch):
     assert bare.losses == res.losses
     assert not any("agg/" in n for n in op_scopes(bare.step).values())
 
+
+
+def test_gin_structure_counters_count_committed_layers():
+    """``gnn.train`` counts each committed GIN layer's structure once in
+    the registry it is given, split as the plan's epilogues; without a
+    registry it counts nowhere, and GCN counts nothing."""
+    g = small_graph(nf=5)
+    cfg = gnn.GNNConfig(model="gin", hidden=8, n_layers=3,
+                        selector="cost_model")
+    reg = MetricsRegistry()
+    res = gnn.train(g, cfg, steps=1, metrics=reg)
+    structures = [ep.structure for ep in res.plan.epilogues]
+    assert structures[0] == "aggregate_first"      # 5 inputs < hidden 8
+    assert structures[1:] == ["transform_first"] * 2
+    snap = reg.snapshot()
+    assert snap == {f"gin.structure.{s}": structures.count(s)
+                    for s in set(structures)}
+    assert sum(snap.values()) == cfg.n_layers
+    gnn.train(g, cfg, steps=1, metrics=reg)
+    assert reg.snapshot() == {k: 2 * v for k, v in snap.items()}
+    gcn = MetricsRegistry()
+    gnn.train(g, dataclasses.replace(cfg, model="gcn"), steps=1, metrics=gcn)
+    assert gcn.snapshot() == {}

@@ -5,9 +5,12 @@ no chip time, instead of on the chip.
 
 Widths are the chip smoke's (``chip_smoke.py``): f32, block size B = 16
 (the community size), features 256 (hidden) and 512 (pubmed's 500 inputs
-lane-padded).  The topology is described inside a module fixture — never
-at import — because only one process at a time may load the TPU library;
-under pytest-xdist only the worker that runs this file loads it.
+lane-padded), and 64 (GIN's hidden width) as one whole block narrower
+than the 128 lanes.  ``kernels.ops`` lane-pads GIN's 50 and 64 to 128
+before any kernel sees them; the whole GIN step below compiles that path.
+The topology is described inside a module fixture — never at import —
+because only one process at a time may load the TPU library; under
+pytest-xdist only the worker that runs this file loads it.
 """
 from __future__ import annotations
 
@@ -135,7 +138,7 @@ KERNELS = ("block_diag", "block_diag_acc", "block_diag_fused",
            "tcgnn_tile_fused_acc", "tcgnn_spmm_dw")
 
 
-@pytest.mark.parametrize("f", (256, 512))
+@pytest.mark.parametrize("f", (64, 256, 512))
 @pytest.mark.parametrize("name", KERNELS)
 def test_kernel_compiles_for_v5e(name, f, one_chip, no_persistent_cache):
     fn, specs = _case(name, f)
@@ -209,6 +212,28 @@ PALLAS_CALL = re.compile(r'^\s*(?:ROOT )?%([\w.\-]+) = .*'
                          r'custom_call_target="tpu_custom_call"', re.M)
 
 
+def _same_program(scoped, bare) -> list:
+    """Assert two compiled steps are the same program, op for op, fusion
+    for fusion, under the same instruction names, once each op's metadata
+    is set aside and each Pallas call is named as in ``scoped``.  Returns
+    the Pallas calls' instruction names in ``scoped``."""
+    def strip(text):
+        # the computations' instructions, without their source metadata
+        # (the module's source-location tables go too)
+        body = "\n".join(line for line in text.splitlines()
+                         if re.match(r"\s*(ROOT |ENTRY )?%", line))
+        return re.sub(r", metadata=\{[^}]*\}", "", body)
+
+    a, b = strip(scoped.as_text()), strip(bare.as_text())
+    calls_a, calls_b = PALLAS_CALL.findall(a), PALLAS_CALL.findall(b)
+    assert len(calls_a) == len(calls_b) >= 6
+    for old, new in zip(calls_b, calls_a):
+        b = re.sub(r"(?<![\w.\-])%" + re.escape(old) + r"(?![\w.\-])",
+                   "%" + new, b)
+    assert a == b
+    return calls_a
+
+
 def test_tier_scopes_change_no_op_for_v5e(one_chip, no_persistent_cache,
                                           monkeypatch):
     """The ``agg/<tier>/<kernel>`` scopes are metadata: with and without
@@ -225,22 +250,83 @@ def test_tier_scopes_change_no_op_for_v5e(one_chip, no_persistent_cache,
     monkeypatch.setattr(adaptgear, "tier_scope",
                         lambda sub, kernel: contextlib.nullcontext())
     bare = _gcn_step_for_v5e(one_chip, monkeypatch)
-
-    def strip(text):
-        # the computations' instructions, without their source metadata
-        # (the module's source-location tables go too)
-        body = "\n".join(line for line in text.splitlines()
-                         if re.match(r"\s*(ROOT |ENTRY )?%", line))
-        return re.sub(r", metadata=\{[^}]*\}", "", body)
-
-    a, b = strip(scoped.as_text()), strip(bare.as_text())
-    calls_a, calls_b = PALLAS_CALL.findall(a), PALLAS_CALL.findall(b)
-    assert len(calls_a) == len(calls_b) >= 6
-    for old, new in zip(calls_b, calls_a):
-        b = re.sub(r"(?<![\w.\-])%" + re.escape(old) + r"(?![\w.\-])",
-                   "%" + new, b)
-    assert a == b
+    calls = _same_program(scoped, bare)
     # each Pallas call lies under its tier's scope
     scopes = op_scopes(scoped)
     assert all(re.search(r"agg/(intra|inter\d)/", scopes[name])
-               for name in calls_a)
+               for name in calls)
+
+
+def _gin_step_for_v5e(one_chip, gin_scopes: bool = True):
+    """The whole full-batch GIN train step compiled for v5e, as the chip
+    runs it, at the cell's widths: 50 inputs, 5 layers of hidden 64, 121
+    classes.  Layer 0 aggregates its 50-wide input (aggregate-first), the
+    others their 64-wide ``X W1`` (transform-first); the plan puts every
+    Pallas kernel family, fused and unfused, and its VJP in one program.
+    ``gin_scopes=False`` compiles it without the ``gin/*`` scopes."""
+    from repro.core import gnn
+    from repro.core.plan import KernelPlan
+    from repro.graphs import graph as G
+    from repro.kernels import ops
+
+    named_scope = jax.named_scope
+
+    def scope(name):
+        if not gin_scopes and name.startswith("gin/"):
+            return contextlib.nullcontext()
+        return named_scope(name)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        mp.setattr(jax, "named_scope", scope)
+        g = G.synth_dataset("pubmed", scale=0.02, seed=0)
+        cfg = gnn.GNNConfig(model="gin", hidden=64, n_layers=5,
+                            inter_buckets=2, selector="fixed")
+        dec = gnn.prepare(g, cfg)
+        eps = gnn.layer_epilogues(cfg, 50, 121)
+        assert [e.structure for e in eps] == (["aggregate_first"]
+                                              + ["transform_first"] * 4)
+        plan = KernelPlan.make(dec, [
+            ("block_diag", "bell", "tcgnn_tile"),
+            ("block_diag_fused", "bell_fused", "tcgnn_tile_fused"),
+            ("block_diag", "csr", "bell_fused"),
+            ("block_diag", "bell", "tcgnn_tile"),
+            ("block_diag_fused", "csr_fused", "bell")], epilogues=eps)
+        params = gnn.init_model(jax.random.PRNGKey(0), cfg, 50, 121)
+        n = dec.n_pad
+        args = (params, gnn._adam_init(params), dec,
+                jnp.zeros((n, 50), F32), jnp.zeros((n,), I32),
+                jnp.zeros((n,), bool), jnp.zeros((n,), F32))
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), args)
+        return gnn.make_train_step(cfg, plan).lower(*shapes).compile()
+
+
+@pytest.fixture(scope="module")
+def gin_step(one_chip, no_persistent_cache):
+    return _gin_step_for_v5e(one_chip)
+
+
+def test_gin_train_step_compiles_for_v5e(gin_step):
+    """The GIN step at 50 -> 64 x 5 -> 121, every Pallas family and its
+    VJP in one program, fused and unfused, at widths 50 and 64."""
+    assert gin_step.as_text().count("tpu_custom_call") >= 6
+
+
+def test_gin_scopes_change_no_op_for_v5e(gin_step, one_chip,
+                                         no_persistent_cache):
+    """``gin/self`` and ``gin/mlp`` are metadata: the v5e GIN step is the
+    same program with and without them.  Both scopes reach the compiled
+    step's op_names, and no Pallas call (every one is an aggregation)
+    lies under either."""
+    from repro.obs import op_scopes
+
+    bare = _gin_step_for_v5e(one_chip, gin_scopes=False)
+    calls = _same_program(gin_step, bare)
+    scopes = op_scopes(gin_step)
+    assert any("gin/self" in s for s in scopes.values())
+    assert any("gin/mlp" in s for s in scopes.values())
+    assert not any("gin/" in scopes[name] for name in calls)
+    assert all("agg/" in scopes[name] for name in calls)
