@@ -92,7 +92,7 @@ class KernelSpec:
     # Pallas-compiled device code (vs. XLA-native gather/segment ops).  The
     # quarantine path (sampling.plan_cache / train.gnn_steps) uses this to
     # attribute a compile/execute failure it cannot pin to one kernel: the
-    # XLA reference kernels (coo/csr) always succeed, so only pallas specs
+    # XLA reference kernel (coo) always succeeds, so only pallas specs
     # are quarantine candidates by default.
     pallas: bool = False
     doc: str = ""

@@ -232,9 +232,9 @@ def test_kernel_fault_async_pipeline_degrades():
 
 def test_unattributable_failure_reraises():
     """Failures that implicate no Pallas kernel must NOT degrade — real
-    bugs fail fast.  A fault injected into a config whose plans are
-    all-XLA (csr_fused on the sparse small graph) never trips, and a
-    synthetic non-kernel error in the step propagates."""
+    bugs fail fast.  A fault injected into ``bell``, which the config's
+    plans (csr_fused on the sparse small graph) never dispatch, never
+    trips, and a synthetic non-kernel error in the step propagates."""
     g = small_graph()
     cfg = base_cfg()
     ref = gnn_steps.train_minibatch(g, cfg, steps=4, eval_batches=0)

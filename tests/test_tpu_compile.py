@@ -149,23 +149,73 @@ def test_kernel_compiles_for_v5e(name, f, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_csr_expansion_has_no_loop_for_v5e(one_chip, no_persistent_cache):
-    """``csr_matvec`` at the full-batch blogcatalog cell's inter-tier shape
-    (88,784 rows, 1,556,811 edges, 256 features) compiles to straight-line
-    code: the row-pointer expansion is a scatter and a prefix sum, not a
-    binary search's ``while`` loop over every edge."""
+def _csr_spec(one_chip, n, nnz):
     from repro.core import formats
-    from repro.kernels.csr import csr_matvec
-
-    n, nnz, f = 88_784, 1_556_811, 256
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    csr = formats.CSR(n, n, spec((n + 1,), I32), spec((nnz,), I32),
-                      spec((nnz,), F32))
-    text = jax.jit(csr_matvec).lower(csr, spec((n, f), F32)).compile().as_text()
+    return formats.CSR(n, n, spec((n + 1,), I32), spec((nnz,), I32),
+                       spec((nnz,), F32))
+
+
+def test_csr_expansion_has_no_loop_for_v5e(one_chip, no_persistent_cache,
+                                           monkeypatch):
+    """``csr_matvec`` at the full-batch blogcatalog cell's inter-tier shape
+    (88,784 rows, 1,556,811 edges, 256 features) compiles to straight-line
+    code: the row-pointer expansion is a scatter and a prefix sum, not a
+    binary search's ``while`` loop over every edge."""
+    from repro.kernels import ops
+    from repro.kernels.csr import csr_matvec
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    n, nnz, f = 88_784, 1_556_811, 256
+    x = jax.ShapeDtypeStruct((n, f), F32, sharding=one_chip)
+    text = jax.jit(csr_matvec).lower(_csr_spec(one_chip, n, nnz),
+                                     x).compile().as_text()
     assert not re.search(r"\bwhile\(", text)
+
+
+_DEF = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])", re.M)
+_SCATTER = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \S+ scatter\(([^)]*)\)",
+                      re.M)
+
+
+def _scatter_update_shapes(text: str) -> list:
+    """The shape of every scatter's updates operand (its last) in an
+    optimized HLO module, looked up by the operand's instruction name."""
+    shapes = dict(_DEF.findall(text))
+    return [shapes[re.findall(r"%([\w.\-]+)", m.group(1))[-1]]
+            for m in _SCATTER.finditer(text)]
+
+
+@pytest.mark.parametrize("n,nnz,f", [
+    (88_784, 1_556_811, 256), (88_784, 1_556_811, 39),   # gcn full cell
+    (56_944, 652_045, 64), (56_944, 652_045, 50)])       # gin cell
+def test_csr_reduce_compiles_for_v5e(n, nnz, f, one_chip,
+                                     no_persistent_cache, monkeypatch):
+    """``csr_matvec`` and its gradient at the full-batch cells' inter-tier
+    sizes: each direction reduces in the Pallas row-tile kernel, and no
+    scatter takes an edge-length update (XLA's segment-sum scatter-add and
+    the gather's transpose are gone; the row-pointer expansion scatters
+    one mark per row)."""
+    from repro.kernels import ops
+    from repro.kernels.csr import csr_matvec
+    from repro.obs import op_scopes
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((n, f), F32, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(
+        lambda x, a: jnp.sum(csr_matvec(a, x) ** 2))).lower(
+            x, _csr_spec(one_chip, n, nnz)).compile()
+    text = compiled.as_text()
+    scopes = op_scopes(compiled)
+    calls = PALLAS_CALL.findall(text)
+    assert sorted(re.search(r"\b(reduce(?:_t)?)\)*/jit", scopes[c]).group(1)
+                  for c in calls) == ["reduce", "reduce_t"]
+    updates = _scatter_update_shapes(text)
+    assert updates            # the expansions' scatters are found
+    assert not any(str(nnz) in shape for shape in updates), updates
 
 
 def _gcn_step_for_v5e(one_chip, monkeypatch):
@@ -330,3 +380,21 @@ def test_gin_scopes_change_no_op_for_v5e(gin_step, one_chip,
     assert any("gin/mlp" in s for s in scopes.values())
     assert not any("gin/" in scopes[name] for name in calls)
     assert all("agg/" in scopes[name] for name in calls)
+
+
+def test_gin_csr_reduces_lie_under_agg_for_v5e(gin_step):
+    """Every call of the ``csr`` kernels' sorted reduce in the v5e GIN
+    step, forward and transposed, lies under its tier's ``agg/`` scope,
+    as ``reduce`` or ``reduce_t``: the device time of both directions
+    counts as aggregation."""
+    from repro.obs import op_scopes
+
+    scopes = op_scopes(gin_step)
+    reduces = [scopes[name] for name in PALLAS_CALL.findall(
+        gin_step.as_text()) if "csr_sorted_reduce" in scopes[name]]
+    where = sorted(re.search(r"agg/\w+/(csr\w*)\)*/(reduce(?:_t)?)/",
+                             s).groups() for s in reduces)
+    # layer 2 commits csr (forward and transposed reduce), layer 4
+    # csr_fused (forward reduce; its backward is XLA's gather transpose)
+    assert where == [("csr", "reduce"), ("csr", "reduce_t"),
+                     ("csr_fused", "reduce")]
