@@ -45,7 +45,7 @@ def run(n: int = 2048, e: int = 30000, fin: int = 64, fout: int = 512,
 
     layer = {
         "unfused": jax.jit(lambda x, w, b: adaptgear.aggregate_transform(
-            dec, x, w, UNFUSED_PLAN, bias=b, acc=False)),
+            dec, x, w, UNFUSED_PLAN, bias=b)),
         "fused": jax.jit(lambda x, w, b: adaptgear.aggregate_transform(
             dec, x, w, FUSED_PLAN, bias=b)),
     }
@@ -226,11 +226,11 @@ def run_models(n: int = 2048, e: int = 30000, fin: int = 512, fout: int = 64,
     }
 
     def sage_unfused(x):
-        agg = adaptgear.aggregate(dec_mean, x, UNFUSED_PLAN, acc=False)
+        agg = adaptgear.aggregate(dec_mean, x, UNFUSED_PLAN)
         return x @ w_s + agg @ w_n + b
 
     def gin_unfused(x):
-        agg = adaptgear.aggregate(dec_sum, x, UNFUSED_PLAN, acc=False)
+        agg = adaptgear.aggregate(dec_sum, x, UNFUSED_PLAN)
         h = (1.0 + gin_p["eps"]) * x + agg
         return jax.nn.relu(h @ w_n + b) @ gin_p["w2"] + gin_p["b2"]
 

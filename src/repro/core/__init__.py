@@ -36,11 +36,11 @@ Architecture (data flow, one arrow per module boundary):
   epilogue self terms: GCN's bias, SAGE's X W_self, GIN's (1+eps) X W1),
   each subgraph dispatched through its registered kernel:
     * unfused matvec      -- Pallas MXU block kernels, XLA gather/segment
-    * matvec_acc          -- accumulation mode: one output buffer threads
-                             through the subgraph list, Pallas kernels seed
-                             their VMEM scratch from it (no per-bucket
-                             partial tensors); enabled on TPU, where it
-                             saves HBM rather than costing interpret steps
+    * matvec_acc          -- one output buffer threads through the
+                             subgraph list, Pallas kernels seed their VMEM
+                             scratch from it (no per-bucket partial
+                             tensors); a kernel without the hook adds its
+                             partial explicitly
     * fused_matvec(_acc)  -- A_s @ (X W) in one pass: the weight stripe
                              lives in VMEM and the transform product is
                              consumed immediately; the custom VJP runs the
@@ -51,8 +51,7 @@ Architecture (data flow, one arrow per module boundary):
                              transform fused paths (csr_fused, sell_fused)
     * fused_dual_matvec   -- the dual-weight epilogue on the diagonal tier:
                              X W_self + A (X W_neigh) with BOTH stripes in
-                             VMEM (the row block is its own source block),
-                             gated on accumulation mode like matvec_acc
+                             VMEM (the row block is its own source block)
 
 Adding a kernel = one KernelSpec registration (name, kinds, format builder,
 matvec / fused_matvec, cost fn) in one file — kernels/csr.py is the

@@ -75,21 +75,15 @@ def tier_scope(sub: Subgraph, kernel: str):
 
 
 def aggregate(dec: Decomposed, x: jax.Array,
-              kernels: Sequence[str] = DEFAULT_KERNELS, *,
-              acc: bool | None = None) -> jax.Array:
+              kernels: Sequence[str] = DEFAULT_KERNELS) -> jax.Array:
     """Y = A @ X via per-subgraph kernels (x reordered, (n_pad, F)).
 
     ``kernels`` is one name per subgraph, or the ``(intra, inter)`` pair
-    shorthand broadcast over inter buckets.  With ``acc=True`` one output
-    buffer is threaded through the subgraph list: kernels exposing
-    ``matvec_acc`` seed their accumulator from it instead of zeros, so no
-    per-bucket partial (n_pad, F) tensors are materialized (kernels without
-    the hook fall back to the explicit add).  ``acc=None`` resolves by
-    backend, like :func:`aggregate_transform`: on by default on TPU (it
-    saves HBM), off in CPU interpret mode (the extra per-grid-step operand
-    costs more than the XLA adds it replaces)."""
-    if acc is None:
-        acc = jax.default_backend() == "tpu"
+    shorthand broadcast over inter buckets.  One output buffer is threaded
+    through the subgraph list: kernels exposing ``matvec_acc`` seed their
+    accumulator from it instead of zeros, so no per-bucket partial
+    (n_pad, F) tensors are materialized; kernels without the hook add
+    their partial explicitly."""
     names = plan_mod.normalize_layer(dec, kernels)
     y = None
     for sub, k in zip(dec.subgraphs, names):
@@ -98,7 +92,7 @@ def aggregate(dec: Decomposed, x: jax.Array,
         with tier_scope(sub, k):
             if y is None:
                 y = spec.matvec(payload, x)
-            elif acc and spec.matvec_acc is not None:
+            elif spec.matvec_acc is not None:
                 y = spec.matvec_acc(payload, x, y)
             else:
                 y = y + spec.matvec(payload, x)
@@ -109,30 +103,24 @@ def aggregate_transform(dec: Decomposed, x: jax.Array, w: jax.Array,
                         kernels: Sequence[str] = DEFAULT_KERNELS,
                         bias: jax.Array | None = None, *,
                         seed: jax.Array | None = None,
-                        h: jax.Array | None = None,
-                        acc: bool | None = None) -> jax.Array:
+                        h: jax.Array | None = None) -> jax.Array:
     """Y = A @ (X W) (+ bias / + seed) with per-subgraph fused/unfused
     kernels.
 
     The transform-first hot path (GCN, and through the epilogue rewrite
     also GIN/SAGE): fused kernels consume the raw features and weight
     directly (H = X W never round-trips HBM); H is materialized once only
-    if some subgraph picked an unfused kernel.  The bias seeds the threaded
-    accumulator, so it rides along for free in accumulation mode.
+    if some subgraph picked an unfused kernel.  The bias seeds the
+    accumulator threaded through the subgraph list (``matvec_acc`` /
+    ``fused_matvec_acc``, as in :func:`aggregate`), so it rides along for
+    free.
 
     ``seed`` generalizes ``bias`` to a full (n, Fo) accumulator seed — the
     epilogue self terms (GIN's ``(1+eps) X W1 + b1``) enter the threaded
     accumulation through it instead of a separate add.  ``h`` optionally
     supplies a precomputed transform for the unfused candidates (GIN's
     ``S = X W1`` is already materialized for the self term; recomputing it
-    here would double the transform).
-
-    ``acc=None`` resolves by backend: on TPU the threaded accumulator saves
-    one full-width HBM tensor per density bucket; on CPU (interpret mode)
-    the extra per-grid-step operand costs more than the XLA adds it
-    replaces, so partial sums stay explicit."""
-    if acc is None:
-        acc = jax.default_backend() == "tpu"
+    here would double the transform)."""
     names = plan_mod.normalize_layer(dec, kernels)
     specs = [REGISTRY.get(k) for k in names]
     if h is None:
@@ -150,14 +138,14 @@ def aggregate_transform(dec: Decomposed, x: jax.Array, w: jax.Array,
             if spec.fused:
                 if y is None:
                     y = spec.fused_matvec(payload, x, w)
-                elif acc and spec.fused_matvec_acc is not None:
+                elif spec.fused_matvec_acc is not None:
                     y = spec.fused_matvec_acc(payload, x, w, y)
                 else:
                     y = y + spec.fused_matvec(payload, x, w)
             else:
                 if y is None:
                     y = spec.matvec(payload, h)
-                elif acc and spec.matvec_acc is not None:
+                elif spec.matvec_acc is not None:
                     y = spec.matvec_acc(payload, h, y)
                 else:
                     y = y + spec.matvec(payload, h)
@@ -167,8 +155,7 @@ def aggregate_transform(dec: Decomposed, x: jax.Array, w: jax.Array,
 def aggregate_transform_dual(dec: Decomposed, x: jax.Array, w: jax.Array,
                              w_self: jax.Array,
                              kernels: Sequence[str] = DEFAULT_KERNELS,
-                             bias: jax.Array | None = None, *,
-                             acc: bool | None = None) -> jax.Array:
+                             bias: jax.Array | None = None) -> jax.Array:
     """Y = X W_self + A @ (X W) (+ bias): the dual-weight (SAGE) epilogue.
 
     Mean normalization is baked into the decomposition's edge values
@@ -177,25 +164,17 @@ def aggregate_transform_dual(dec: Decomposed, x: jax.Array, w: jax.Array,
     accumulation.  When the first tier's committed kernel provides the
     ``fused_dual_matvec`` hook (the diagonal tier's Pallas kernel), the
     self-weight stripe rides in VMEM next to the neighbor stripe and the
-    self term never materializes separately; otherwise it seeds the
-    accumulator as a dense XLA matmul (still only (n, Fo)-wide — the
-    (n, Fi) aggregation intermediate of the unfused layer is gone either
-    way).
-
-    The hook is gated on accumulation mode (``acc=None`` resolves by
-    backend, like :func:`aggregate_transform`): it exists to keep the self
-    term out of HBM, which only pays on TPU — in CPU interpret mode the
-    extra per-grid-step matmul costs more than the one XLA matmul it
-    replaces, so the seed path runs there."""
-    if acc is None:
-        acc = jax.default_backend() == "tpu"
+    self term never materializes separately (the bias seeds it through
+    ``fused_dual_matvec_acc`` where the kernel has that hook too);
+    otherwise it seeds the accumulator as a dense XLA matmul (still only
+    (n, Fo)-wide — the (n, Fi) aggregation intermediate of the unfused
+    layer is gone either way)."""
     names = plan_mod.normalize_layer(dec, kernels)
     first = REGISTRY.get(names[0])
-    if acc and first.fused_dual_matvec is not None:
+    if first.fused_dual_matvec is not None:
         payload = dec.subgraphs[0].formats[first.payload_key]
         with tier_scope(dec.subgraphs[0], names[0]):
-            if (bias is not None and acc
-                    and first.fused_dual_matvec_acc is not None):
+            if bias is not None and first.fused_dual_matvec_acc is not None:
                 y0 = jnp.broadcast_to(bias.astype(x.dtype),
                                       (x.shape[0], w.shape[-1]))
                 seed = first.fused_dual_matvec_acc(payload, x, w, w_self, y0)
@@ -214,7 +193,7 @@ def aggregate_transform_dual(dec: Decomposed, x: jax.Array, w: jax.Array,
     sub_dec = Decomposed(n=dec.n, n_pad=dec.n_pad, block_size=dec.block_size,
                          perm=dec.perm, inv_perm=dec.inv_perm,
                          subgraphs=tuple(rest), stats=None)
-    return aggregate_transform(sub_dec, x, w, rest_names, seed=seed, acc=acc)
+    return aggregate_transform(sub_dec, x, w, rest_names, seed=seed)
 
 
 def aggregate_full_static(dec: Decomposed, x: jax.Array,

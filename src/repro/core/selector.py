@@ -13,8 +13,8 @@ subgraph (intra tier + every inter density bucket):
 * ``cost_model`` (TPU adaptation, beyond-paper): an analytic two-term
   roofline estimate (compute term = FLOPs/peak, memory term = bytes/bw) per
   candidate, provided by each kernel's registry ``cost`` fn.  Used when
-  wall-clock probing is impossible -- inside a traced computation, or during
-  the multi-pod dry-run where kernels are only lowered, never run.  The
+  wall-clock probing is impossible or cannot amortize -- inside a traced
+  computation, or per sampled batch (``sampling.plan_cache``).  The
   model's constants can be calibrated from feedback probes (``calibrate``),
   closing the loop between the two modes.
 
@@ -488,9 +488,9 @@ class AdaptiveSelector:
                              hw: HwModel | None = None) -> HwModel:
         """Fit a global time-scale from probes so the analytic model's
         *absolute* numbers match this machine (its *ranking* is what the
-        dry-run uses).  Fitted against the *raw* kernel times: the selection
-        surcharge (shared-transform share) is not part of any kernel's own
-        cost fn."""
+        cost-model selection uses).  Fitted against the *raw* kernel
+        times: the selection surcharge (shared-transform share) is not part
+        of any kernel's own cost fn."""
         hw = hw or default_hw()
         if not self._raw:
             return hw

@@ -7,10 +7,6 @@ Design (multi-host ready, exercised single-host in tests):
     loop only blocks for the host copy, not the disk write
   * integrity: every array file carries a crc32 in the manifest; restore
     validates and falls back to the previous step on mismatch
-  * resharding restore: arrays are saved as full (host-replicated) numpy;
-    ``restore`` accepts a target sharding tree and uses
-    jax.device_put(..., sharding) so the same checkpoint restores onto any
-    mesh (elastic scaling path)
   * aux payload: ``save(..., aux=...)`` pickles an arbitrary host-side
     object (training cursor, sampler draw count, PlanCache state) next to
     the array tree with its own crc — the recovery contract for the
@@ -168,11 +164,9 @@ class CheckpointManager:
         with open(path, "rb") as f:
             return pickle.load(f)
 
-    def restore(self, tree_like: Any, step: int | None = None,
-                shardings: Any = None) -> tuple[Any, int]:
-        """Restore into the structure of ``tree_like``; optionally place onto
-        ``shardings`` (a matching tree of NamedSharding) — this is the
-        elastic/re-mesh path."""
+    def restore(self, tree_like: Any,
+                step: int | None = None) -> tuple[Any, int]:
+        """Restore into the structure and dtypes of ``tree_like``."""
         if step is None:
             step = self.latest_valid_step()
             if step is None:
@@ -181,13 +175,8 @@ class CheckpointManager:
         data = np.load(os.path.join(d, "arrays.npz"))
         flat, treedef = _flatten_with_paths(tree_like)
         leaves = []
-        shard_flat = (jax.tree.leaves(shardings) if shardings is not None
-                      else [None] * len(flat))
-        for (key, like), shd in zip(flat, shard_flat):
+        for key, like in flat:
             arr = data[key]
             assert arr.shape == tuple(like.shape), (key, arr.shape, like.shape)
-            if shd is not None:
-                leaves.append(jax.device_put(arr, shd))
-            else:
-                leaves.append(jax.numpy.asarray(arr, dtype=like.dtype))
+            leaves.append(jax.numpy.asarray(arr, dtype=like.dtype))
         return jax.tree.unflatten(treedef, leaves), step

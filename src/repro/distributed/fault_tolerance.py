@@ -2,8 +2,9 @@
 
 On a real 1000-node cluster these hooks connect to the coordination service;
 here every mechanism is implemented and unit-tested against simulated
-heartbeats / step-time streams, and the training loops (launch/train.py,
-train/gnn_steps.py) drive them for real on the CPU host.
+heartbeats / step-time streams, and the mini-batch training loop
+(train/gnn_steps.py) and the server (serve/server.py) drive them for real
+on the host.
 
 Components:
   HeartbeatMonitor  -- per-host liveness with timeout -> dead-host set;
@@ -11,7 +12,7 @@ Components:
                        host is not re-reported forever
   StragglerDetector -- per-host step-time EWMA; z-score over the fleet
                        median flags stragglers (mitigation: demote the host's
-                       data shard, or trigger elastic re-mesh)
+                       data shard)
   reassign_shards   -- deterministic data-shard reassignment when hosts die:
                        surviving hosts take over orphaned shards round-robin
                        (restart-stable: pure function of (n_shards, alive))

@@ -60,85 +60,3 @@ def coo_spmm_dense_ref(rows, cols, vals, x, n_rows):
     a = jnp.zeros((n_rows, x.shape[0]), jnp.float32)
     a = a.at[rows, cols].add(vals)
     return (a @ x.astype(jnp.float32)).astype(x.dtype)
-
-
-# --- attention -------------------------------------------------------------
-
-def mha(q, k, v, *, causal: bool = True, scale: float | None = None,
-        bias=None) -> jax.Array:
-    """Reference multi-head attention. q: (B, Hq, S, D); k/v: (B, Hkv, T, D).
-    GQA handled by head-group broadcast."""
-    b, hq, s, d = q.shape
-    hkv = k.shape[1]
-    g = hq // hkv
-    qf = q.astype(jnp.float32).reshape(b, hkv, g, s, d)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    sc = (d ** -0.5) if scale is None else scale
-    logits = jnp.einsum("bhgsd,bhtd->bhgst", qf, kf) * sc
-    if bias is not None:
-        logits = logits + bias
-    if causal:
-        t = k.shape[2]
-        mask = jnp.tril(jnp.ones((s, t), bool), k=t - s)
-        logits = jnp.where(mask, logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgst,bhtd->bhgsd", p, vf)
-    return out.reshape(b, hq, s, v.shape[-1]).astype(q.dtype)
-
-
-# --- RWKV-6 / gated linear recurrence ---------------------------------------
-
-def rwkv6_linear_attention(r, k, v, w, u) -> jax.Array:
-    """RWKV-6 (Finch) recurrence, sequential oracle.
-
-    r,k,v: (B, H, T, D); w: (B, H, T, D) per-step decay in (0,1);
-    u: (H, D) bonus for the current token.
-      S_t = diag(w_t) S_{t-1} + k_t^T v_t
-      o_t = r_t (S_{t-1} + diag(u) k_t^T v_t)
-    Shapes follow arXiv:2404.05892 eq. (17)-(19).
-    """
-    B, H, T, D = r.shape
-    rf, kf, vf, wf = (a.astype(jnp.float32) for a in (r, k, v, w))
-    uf = u.astype(jnp.float32)
-
-    def step(S, inp):
-        rt, kt, vt, wt = inp          # (B,H,D) each
-        kv = kt[..., :, None] * vt[..., None, :]          # (B,H,D,D)
-        out = jnp.einsum("bhd,bhde->bhe", rt, S + uf[:, :, None] * kv)
-        S = wt[..., :, None] * S + kv
-        return S, out
-
-    S0 = jnp.zeros((B, H, D, D), jnp.float32)
-    inputs = tuple(jnp.moveaxis(a, 2, 0) for a in (rf, kf, vf, wf))
-    _, outs = jax.lax.scan(step, S0, inputs)
-    return jnp.moveaxis(outs, 0, 2).astype(r.dtype)  # (B,H,T,D)
-
-
-# --- selective SSM (Mamba) ---------------------------------------------------
-
-def mamba_ssm(x, dt, A, Bc, Cc, D) -> jax.Array:
-    """Selective state space scan, sequential oracle.
-
-    x: (B, T, d_inner); dt: (B, T, d_inner) (post-softplus);
-    A: (d_inner, d_state); Bc/Cc: (B, T, d_state); D: (d_inner,)
-      h_t = exp(dt_t * A) h_{t-1} + dt_t * B_t * x_t ;  y_t = C_t . h_t + D x_t
-    """
-    xb, dtb, Bb, Cb = (a.astype(jnp.float32) for a in (x, dt, Bc, Cc))
-    Af = A.astype(jnp.float32)
-
-    def step(h, inp):
-        xt, dtt, Bt, Ct = inp
-        dA = jnp.exp(dtt[..., None] * Af)                  # (B, d_inner, d_state)
-        dBx = (dtt * xt)[..., None] * Bt[:, None, :]       # (B, d_inner, d_state)
-        h = dA * h + dBx
-        y = jnp.einsum("bds,bs->bd", h, Ct)
-        return h, y
-
-    Bsz, T, d_inner = x.shape
-    h0 = jnp.zeros((Bsz, d_inner, Af.shape[-1]), jnp.float32)
-    inputs = (jnp.moveaxis(xb, 1, 0), jnp.moveaxis(dtb, 1, 0),
-              jnp.moveaxis(Bb, 1, 0), jnp.moveaxis(Cb, 1, 0))
-    _, ys = jax.lax.scan(step, h0, inputs)
-    y = jnp.moveaxis(ys, 0, 1) + xb * D.astype(jnp.float32)
-    return y.astype(x.dtype)

@@ -8,8 +8,6 @@ prints the latency/shedding/degradation report:
   PYTHONPATH=src python -m repro.launch.serve --dataset cora --scale 0.2 \\
       --train-steps 20 --qps 200 --seconds 2 --deadline-ms 100 \\
       --plan-cache /tmp/plans.bin
-
-The LM serving demo that used to live here moved to examples/serve_lm.py.
 """
 from __future__ import annotations
 

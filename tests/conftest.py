@@ -1,8 +1,7 @@
 import os
 import sys
 
-# tests see exactly 1 CPU device (the dry-run, and only the dry-run, forces
-# 512); make sure no leaked XLA_FLAGS changes that.
+# tests see exactly 1 CPU device; make sure no leaked XLA_FLAGS changes that.
 os.environ.pop("XLA_FLAGS", None)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

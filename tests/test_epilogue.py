@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import example, given, settings, st
+from _tiers import SpiedRegistry, tier_sum
 
 from repro.core import adaptgear, decompose, epilogue as ep_mod, gnn
 from repro.core import selector as sel_mod
@@ -253,12 +254,12 @@ def test_minibatch_gin_fused_plan_at_one_trace():
     assert np.isfinite(res.losses).all()
 
 
-def test_dual_weight_kernel_hook_equivalence(rng):
+def test_dual_weight_kernel_hook_equivalence(rng, monkeypatch):
     """The dual-stripe Pallas kernel (both weight stripes in VMEM,
-    ``fused_dual_matvec``/``_acc``) == the seed path, forward and grads,
-    with and without the threaded bias.  ``acc=True`` forces the hook on
-    (its backend default keeps it TPU-only — in interpret mode the extra
-    per-grid-step matmul is slower than the XLA seed it replaces)."""
+    ``fused_dual_matvec``, or ``_acc`` when the bias seeds it) runs on the
+    diagonal tier and matches the layer written out tier by tier, with
+    each tier's plain fused hook and the self term and bias added
+    explicitly: forward and grads, with and without the bias."""
     g, a, dec, cfg = cached("sage", 2)
     xr = adaptgear.to_reordered(dec, jnp.asarray(
         rng.standard_normal((g.n, 5)), jnp.float32))
@@ -268,22 +269,30 @@ def test_dual_weight_kernel_hook_equivalence(rng):
     names = ("block_diag_fused", "bell_fused", "bell_fused")
     assert REGISTRY.get(names[0]).fused_dual_matvec is not None
 
-    for bias in (b, None):
+    for bias, attr in ((b, "fused_dual_matvec_acc"),
+                       (None, "fused_dual_matvec")):
         hook = lambda xr, wn, ws: adaptgear.aggregate_transform_dual(  # noqa
-            dec, xr, wn, ws, names, bias=bias, acc=True)
-        seed = lambda xr, wn, ws: adaptgear.aggregate_transform_dual(  # noqa
-            dec, xr, wn, ws, names, bias=bias, acc=False)
-        np.testing.assert_allclose(np.asarray(hook(xr, wn, ws)),
-                                   np.asarray(seed(xr, wn, ws)),
+            dec, xr, wn, ws, names, bias=bias)
+        summed = lambda xr, wn, ws: tier_sum(  # noqa: E731
+            dec, names, xr, wn, ws, bias=bias)
+        spied = SpiedRegistry(names[0], attr)
+        with monkeypatch.context() as mp:
+            mp.setattr(adaptgear, "REGISTRY", spied)
+            y = hook(xr, wn, ws)
+        assert spied.calls == 1, attr
+        np.testing.assert_allclose(np.asarray(y),
+                                   np.asarray(summed(xr, wn, ws)),
                                    atol=1e-5, rtol=1e-5)
-        g_h = jax.grad(lambda *a: jnp.sum(hook(*a) ** 2), (0, 1, 2))(xr, wn, ws)
-        g_s = jax.grad(lambda *a: jnp.sum(seed(*a) ** 2), (0, 1, 2))(xr, wn, ws)
+        g_h = jax.grad(lambda *a: jnp.sum(hook(*a) ** 2),
+                       (0, 1, 2))(xr, wn, ws)
+        g_s = jax.grad(lambda *a: jnp.sum(summed(*a) ** 2),
+                       (0, 1, 2))(xr, wn, ws)
         for p, q in zip(g_h, g_s):
             np.testing.assert_allclose(np.asarray(p), np.asarray(q),
                                        atol=1e-3, rtol=1e-3)
-    # bias grad through the acc-threaded broadcast
+    # bias grad through the threaded broadcast
     db = jax.grad(lambda b: jnp.sum(adaptgear.aggregate_transform_dual(
-        dec, xr, wn, ws, names, bias=b, acc=True)))(b)
+        dec, xr, wn, ws, names, bias=b)))(b)
     np.testing.assert_allclose(np.asarray(db),
                                np.full((7,), dec.n_pad, np.float32),
                                atol=1e-3, rtol=1e-4)

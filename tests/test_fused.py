@@ -2,17 +2,19 @@
 
 Covers: fused kernels vs the unfused dense reference (forward AND grads wrt
 inputs and params, per-dtype tolerances) for GCN over every bucket count;
-accumulation-mode equivalence; per-bucket blocked-ELL tiling; the _f_tile
-divisor fix; selector integration (fused candidates competing in both
-modes); and bucket-count autotuning."""
+every accumulate hook against the explicit per-tier sum; per-bucket
+blocked-ELL tiling; the _f_tile divisor fix; selector integration (fused
+candidates competing in both modes); and bucket-count autotuning."""
 import functools
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from _tiers import SpiedRegistry, tier_sum
 
 from repro.core import adaptgear, decompose, formats, gnn, selector
+from repro.core import plan as plan_mod
 from repro.core.plan import KernelPlan
 from repro.graphs import graph as G
 from repro.kernels import ops
@@ -91,34 +93,69 @@ def test_fused_gcn_matches_dense_fwd_and_grad(ik, ek, k, dt, rng):
                                    err_msg=f"{ik}/{ek} k={k} {name}")
 
 
+# hook -> (layer, plan).  The plan's later tiers thread the accumulator
+# through the hook; where the hook sits on the first (diagonal) tier, the
+# bias seeds the accumulator it threads.
+ACC_HOOKS = {
+    "block_diag.matvec_acc": ("transform", ("block_diag", "bell")),
+    "bell.matvec_acc": ("aggregate", ("block_diag", "bell")),
+    "tcgnn_tile.matvec_acc": ("aggregate", ("block_diag", "tcgnn_tile")),
+    "block_diag_fused.fused_matvec_acc":
+        ("transform", ("block_diag_fused", "bell")),
+    "bell_fused.fused_matvec_acc":
+        ("transform", ("block_diag_fused", "bell_fused")),
+    "tcgnn_tile_fused.fused_matvec_acc":
+        ("transform", ("block_diag_fused", "tcgnn_tile_fused")),
+    "block_diag_fused.fused_dual_matvec_acc":
+        ("dual", ("block_diag_fused", "bell_fused")),
+}
+
+
 @pytest.mark.parametrize("k", [1, 2, 4])
-def test_accumulation_mode_equivalence(k, rng):
-    """aggregate(acc=True) == aggregate(acc=False), and likewise for the
-    fused transform path, including grads through the threaded buffer."""
+@pytest.mark.parametrize("hook", list(ACC_HOOKS))
+def test_accumulation_mode_equivalence(hook, k, rng, monkeypatch):
+    """Each accumulate hook, the one dispatch path, runs on every tier it
+    is planned for and matches the explicit per-tier sum, forward and
+    grads through the threaded buffer."""
+    layer, plan = ACC_HOOKS[hook]
+    kernel, attr = hook.split(".")
     g, a, dec = cached(k)
-    x = jnp.asarray(rng.standard_normal((g.n, 6)), jnp.float32)
+    names = plan_mod.normalize_layer(dec, plan)
+    xr = adaptgear.to_reordered(dec, jnp.asarray(
+        rng.standard_normal((g.n, 6)), jnp.float32))
     w = jnp.asarray(rng.standard_normal((6, 4)), jnp.float32)
+    ws = jnp.asarray(rng.standard_normal((6, 4)), jnp.float32)
     b = jnp.asarray(rng.standard_normal(4), jnp.float32)
-    xr = adaptgear.to_reordered(dec, x)
-
-    y_acc = adaptgear.aggregate(dec, xr, ("block_diag", "bell"), acc=True)
-    y_sum = adaptgear.aggregate(dec, xr, ("block_diag", "bell"), acc=False)
-    np.testing.assert_allclose(np.asarray(y_acc), np.asarray(y_sum),
-                               atol=1e-5, rtol=1e-5)
-
-    for names in (("block_diag_fused", "bell_fused"), ("block_diag", "bell")):
-        f_acc = lambda xr, w, b: adaptgear.aggregate_transform(  # noqa: E731
-            dec, xr, w, names, bias=b, acc=True)
-        f_sum = lambda xr, w, b: adaptgear.aggregate_transform(  # noqa: E731
-            dec, xr, w, names, bias=b, acc=False)
-        np.testing.assert_allclose(np.asarray(f_acc(xr, w, b)),
-                                   np.asarray(f_sum(xr, w, b)),
-                                   atol=1e-5, rtol=1e-5, err_msg=str(names))
-        g_acc = jax.grad(lambda *a: jnp.sum(f_acc(*a) ** 2), (0, 1, 2))(xr, w, b)
-        g_sum = jax.grad(lambda *a: jnp.sum(f_sum(*a) ** 2), (0, 1, 2))(xr, w, b)
-        for p, q in zip(g_acc, g_sum):
-            np.testing.assert_allclose(np.asarray(p), np.asarray(q),
-                                       atol=1e-3, rtol=1e-3, err_msg=str(names))
+    if layer == "aggregate":
+        args = (xr,)
+        run = lambda xr: adaptgear.aggregate(dec, xr, names)  # noqa: E731
+        ref = lambda xr: tier_sum(dec, names, xr)  # noqa: E731
+    elif layer == "transform":
+        args = (xr, w, b)
+        run = lambda xr, w, b: adaptgear.aggregate_transform(  # noqa: E731
+            dec, xr, w, names, bias=b)
+        ref = lambda xr, w, b: tier_sum(dec, names, xr, w,  # noqa: E731
+                                        bias=b)
+    else:
+        args = (xr, w, ws, b)
+        run = lambda xr, w, ws, b: (  # noqa: E731
+            adaptgear.aggregate_transform_dual(dec, xr, w, ws, names,
+                                               bias=b))
+        ref = lambda xr, w, ws, b: tier_sum(dec, names, xr, w, ws,  # noqa
+                                            bias=b)
+    spied = SpiedRegistry(kernel, attr)
+    monkeypatch.setattr(adaptgear, "REGISTRY", spied)
+    y = run(*args)
+    assert spied.calls == names.count(kernel), (hook, names)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ref(*args)),
+                               atol=1e-5, rtol=1e-5, err_msg=hook)
+    cot = jnp.asarray(rng.standard_normal(y.shape), jnp.float32)
+    argnums = tuple(range(len(args)))
+    g_run = jax.grad(lambda *a: jnp.sum(run(*a) * cot), argnums)(*args)
+    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) * cot), argnums)(*args)
+    for p, q in zip(g_run, g_ref):
+        np.testing.assert_allclose(np.asarray(p), np.asarray(q),
+                                   atol=1e-3, rtol=1e-3, err_msg=hook)
 
 
 def test_fused_plan_through_training(rng):

@@ -86,17 +86,6 @@ def test_memory_overhead_topology(citeseer):
     assert topo_bytes < 50 * feat_bytes
 
 
-def test_lm_moe_adaptgear_hook():
-    """The MoE dispatch selector must route big-E configs to the sparse
-    path (DESIGN.md §4)."""
-    from repro import configs
-    from repro.models import blocks as B
-    moe16 = configs.get_config("deepseek_moe_16b").moe_cfg()
-    assert B.choose_moe_path(moe16, n_tokens=1 << 20) == "sparse"
-    v3 = configs.get_config("deepseek_v3_671b").moe_cfg()
-    assert B.choose_moe_path(v3, n_tokens=1 << 20) == "sparse"
-
-
 def test_train_step_takes_graph_as_argument(citeseer):
     """The full-batch step lowers with the decomposition as arguments, not
     as constants: no array constant beyond a few scalars' worth, so the

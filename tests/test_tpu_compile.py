@@ -138,10 +138,46 @@ KERNELS = ("block_diag", "block_diag_acc", "block_diag_fused",
            "tcgnn_tile_fused_acc", "tcgnn_spmm_dw")
 
 
-@pytest.mark.parametrize("f", (64, 256, 512))
-@pytest.mark.parametrize("name", KERNELS)
-def test_kernel_compiles_for_v5e(name, f, one_chip, no_persistent_cache):
-    fn, specs = _case(name, f)
+# widths below the 128 lanes that the gin and mb cells run: 50 (PPI's
+# inputs), 22 and 39 (amazon0505's and blogcatalog's classes)
+NARROW = (22, 39, 50)
+
+
+def _narrow_case(name: str, f: int):
+    """(callable, operand shapes) for a ``block_diag`` kernel at a narrow
+    width, through the ``kernels.ops`` wrapper that lane-pads it, as the
+    chip runs it: ``f`` is the aggregated width, the fused kernels'
+    output (their input is 256 wide, as in the cells' last layers)."""
+    from repro.kernels import ops
+    wide = ("_fused" in name or "_dual" in name)
+    fi = 256 if wide else f
+    cases = {
+        "block_diag": (ops.block_diag_matvec, [(NB, B, B), (N, f)]),
+        "block_diag_acc": (ops.block_diag_matvec_acc,
+                           [(NB, B, B), (N, f), (N, f)]),
+        "block_diag_fused": (ops.block_diag_fused_matvec,
+                             [(NB, B, B), (N, fi), (fi, f)]),
+        "block_diag_fused_acc": (ops.block_diag_fused_matvec_acc,
+                                 [(NB, B, B), (N, fi), (fi, f), (N, f)]),
+        "block_diag_dual": (ops.block_diag_dual_matvec,
+                            [(NB, B, B), (N, fi), (fi, f), (fi, f)]),
+        "block_diag_dual_acc": (ops.block_diag_dual_matvec_acc,
+                                [(NB, B, B), (N, fi), (fi, f), (fi, f),
+                                 (N, f)]),
+    }
+    return cases[name]
+
+
+CASES = ([(name, f) for name in KERNELS for f in (64, 256, 512)]
+         + [(name, f) for name in KERNELS[:6] for f in NARROW])
+
+
+@pytest.mark.parametrize("name,f", CASES)
+def test_kernel_compiles_for_v5e(name, f, one_chip, no_persistent_cache,
+                                 monkeypatch):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    fn, specs = (_narrow_case if f in NARROW else _case)(name, f)
     args = [jax.ShapeDtypeStruct(*(s if isinstance(s[0], tuple) else (s, F32)),
                                  sharding=one_chip)
             for s in specs]
@@ -220,17 +256,15 @@ def test_csr_reduce_compiles_for_v5e(n, nnz, f, one_chip,
 
 def _gcn_step_for_v5e(one_chip, monkeypatch):
     """The whole full-batch GCN train step compiled for v5e, as the chip
-    runs it: Pallas compiled (not interpreted) and the accumulating
-    dispatch on, at the published widths (500 inputs, hidden 256, 3
-    layers) on a small graph, with a plan that puts every Pallas kernel
-    family and its VJP in one program."""
+    runs it: Pallas compiled (not interpreted), at the published widths
+    (500 inputs, hidden 256, 3 layers) on a small graph, with a plan that
+    puts every Pallas kernel family and its VJP in one program."""
     from repro.core import gnn
     from repro.core.plan import KernelPlan
     from repro.graphs import graph as G
     from repro.kernels import ops
 
     monkeypatch.setattr(ops, "_interpret", lambda: False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     g = G.synth_dataset("pubmed", scale=0.02, seed=0)
     cfg = gnn.GNNConfig(model="gcn", hidden=256, n_layers=3,
                         inter_buckets=2, selector="fixed")
@@ -328,7 +362,6 @@ def _gin_step_for_v5e(one_chip, gin_scopes: bool = True):
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_interpret", lambda: False)
-        mp.setattr(jax, "default_backend", lambda: "tpu")
         mp.setattr(jax, "named_scope", scope)
         g = G.synth_dataset("pubmed", scale=0.02, seed=0)
         cfg = gnn.GNNConfig(model="gin", hidden=64, n_layers=5,
